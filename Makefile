@@ -18,11 +18,12 @@ test-fast:
 	  tests/test_query_parity.py tests/test_structured.py \
 	  tests/test_compose.py tests/test_recompute.py
 
-# kernel lane: Pallas-vs-oracle parity (interpret mode), the fused
-# batched-walk grid, launch accounting, and calibration round-trips
+# kernel lane: Pallas-vs-oracle parity (interpret mode), compiles for a
+# described v5e chip, the fused batched-walk grid, launch accounting, and
+# calibration round-trips
 test-kernels:
 	timeout $(TIMEOUT) env PYTHONPATH=src $(PY) -m pytest -x -q \
-	  tests/test_kernels.py tests/test_backend_parity.py
+	  tests/test_kernels.py tests/test_backend_parity.py tests/test_tpu_compile.py
 
 # the CI multi-device lane locally: 8 forced host CPU devices so the
 # shard_map collective walkers and mesh integration paths really execute

@@ -69,6 +69,11 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -83,4 +83,9 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     run()

@@ -29,7 +29,7 @@ from repro.core.pipeline import ProvenanceIndex
 from repro.core.recompute import recompute_rows
 from repro.dataprep.table import Table
 from repro.dataprep.tracked import track
-from repro.dataprep.usecases import make_census
+from repro.dataprep.usecases import build_deep_chain, chain_step, make_census, make_chain_table
 from repro.provenance import QuerySession, prov
 
 
@@ -153,40 +153,6 @@ def run(quick: bool = False):
 # ---------------------------------------------------------------------------
 # Batched multi-hop Q1/Q2: per-hop walk vs batch walk vs composed hop-cache
 # ---------------------------------------------------------------------------
-def _chain_step(d, i):
-    """Op ``i`` of the deterministic deep chain (replayable: the same step
-    sequence builds the merged AND the federated variants identically)."""
-    kind = i % 4
-    if kind == 0:
-        return d.value_transform("x", "scale", factor=1.01)
-    if kind == 1:
-        mask = np.ones(d.table.n_rows, dtype=bool)
-        mask[i :: 17] = False                         # drop a sliver per hop
-        return d.filter_rows(mask)
-    if kind == 2:
-        return d.normalize(["x"], kind="zscore")
-    return d.oversample(frac=0.05, seed=i)
-
-
-def _chain_table(seed, n):
-    rng = np.random.default_rng(seed)
-    return Table.from_columns({
-        "k": rng.integers(0, n // 2, n).astype(np.float32),
-        "x": rng.normal(size=n).astype(np.float32),
-        "g": rng.integers(0, 4, n).astype(np.float32),
-    })
-
-
-def build_deep_chain(seed=0, n=4000, n_ops=12):
-    """A >=10-op chain so multi-hop composition has distance to amortize."""
-    idx = ProvenanceIndex("deep-chain")
-    d = track(_chain_table(seed, n), idx, "chain_src")
-    for i in range(n_ops):
-        d = _chain_step(d, i)
-    d.mark_sink()
-    return idx, d.dataset_id
-
-
 def run_batch_vs_walk(quick: bool = False, n_probes: int = 64):
     idx, sink = build_deep_chain(n=1000 if quick else 4000,
                                  n_ops=10 if quick else 14)
@@ -605,14 +571,14 @@ def build_split_chain(seed=0, n=4000, n_ops=12):
 
     cut = n_ops // 2
     prep = ProvenanceIndex("prep")
-    d = track(_chain_table(seed, n), prep, "chain_src")
+    d = track(make_chain_table(seed, n), prep, "chain_src")
     for i in range(cut):
-        d = _chain_step(d, i)
+        d = chain_step(d, i)
     boundary = d.dataset_id
     serve = ProvenanceIndex("serve")
     s = track(d.table, serve, "ingest")
     for i in range(cut, n_ops):
-        s = _chain_step(s, i)
+        s = chain_step(s, i)
     s.mark_sink()
     catalog = ProvCatalog("bench-fed")
     catalog.register("prep", prep).register("serve", serve)
@@ -833,6 +799,9 @@ def _write_trajectory(results: dict) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

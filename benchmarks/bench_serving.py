@@ -30,12 +30,8 @@ import time
 
 import numpy as np
 
-try:
-    from benchmarks.bench_query import build_deep_chain
-except ImportError:                         # run as a script: sibling import
-    from bench_query import build_deep_chain
-
 from repro.core.hopcache import ComposedIndex
+from repro.dataprep.usecases import build_deep_chain
 from repro.provenance import QuerySession, prov
 from repro.serve import ServingTier
 
@@ -268,6 +264,9 @@ def _merge_trajectory(section: dict) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

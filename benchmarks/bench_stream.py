@@ -335,6 +335,9 @@ def _merge_trajectory(section: dict) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -63,23 +63,12 @@ def default_path() -> str:
         os.path.expanduser("~"), ".cache", "repro", "calibration.json")
 
 
-def device_kind(allow_import: bool = True) -> str:
-    """Device-kind key for the calibration file (e.g. ``TPU-v5e`` /
-    ``cpu``).  With ``allow_import=False`` jax is only consulted when some
-    other module already imported it — the jax-free load path."""
-    import sys
+def device_kind() -> str:
+    """Device-kind key for the calibration file (e.g. ``TPU-v5-lite`` /
+    ``cpu``)."""
+    import jax
 
-    if allow_import or "jax" in sys.modules:
-        try:
-            import jax
-
-            devs = jax.devices()
-            if devs:
-                return str(devs[0].device_kind).replace(" ", "-")
-            return str(jax.default_backend())
-        except Exception:  # pragma: no cover - broken jax install
-            pass
-    return "cpu"
+    return str(jax.devices()[0].device_kind).replace(" ", "-")
 
 
 # ---------------------------------------------------------------------------
@@ -236,9 +225,9 @@ def load_constants(path: Optional[str] = None,
                    device: Optional[str] = None) -> Optional[Constants]:
     """Constants for this device kind from the calibration file, or None.
 
-    jax-free: when jax is not already imported the device key falls back to
-    ``"cpu"``; a file holding exactly one device entry matches regardless
-    (one-machine calibration files shouldn't depend on import order).
+    Only the entry fitted on ``device`` (default: this process's device
+    kind) applies: constants measured on another device would route this
+    one by the wrong crossover.
     """
     path = path or default_path()
     if not os.path.exists(path):
@@ -251,12 +240,9 @@ def load_constants(path: Optional[str] = None,
     devices = data.get("devices")
     if not isinstance(devices, dict) or not devices:
         return None
-    key = device or device_kind(allow_import=False)
+    key = device or device_kind()
     if key not in devices:
-        if len(devices) == 1:
-            key = next(iter(devices))
-        else:
-            return None
+        return None
     entry = devices[key]
     fields = {f.name for f in dataclasses.fields(Constants)}
     kwargs = {k: v for k, v in entry.items() if k in fields}

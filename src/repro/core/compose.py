@@ -22,7 +22,6 @@ classic matrix-chain dynamic program on the (rows, cols/32-word) dims.
 """
 from __future__ import annotations
 
-import sys
 import warnings
 from typing import List, Optional, Sequence, Tuple
 
@@ -59,25 +58,19 @@ HAVE_SCIPY = _sp is not None
 
 
 def resolve_use_pallas(use_pallas: Optional[bool]) -> bool:
-    """Resolve the tri-state kernel flag without forcing a jax import.
+    """Resolve the tri-state kernel flag from the platform.
 
     ``None`` — the default everywhere above the kernel layer — means
-    "Pallas iff this process already runs on TPU": hosts resolve ``False``
-    without ever importing jax, so numpy-only paths stay jax-free.
+    "Pallas iff this process runs on TPU" (:func:`repro.kernels.ops.on_tpu`).
     Explicit ``True`` off-TPU still works (interpret-mode emulation, the
     parity-test path) but is deprecated as a routing choice — emulation is
     never the faster backend — and warns.
     """
-    if use_pallas is None:
-        if "jax" not in sys.modules:
-            return False
-        from repro.kernels import ops as K
+    from repro.kernels import ops as K
 
+    if use_pallas is None:
         return K.on_tpu()
     if use_pallas:
-        # the caller wants Pallas kernels, so importing jax costs nothing new
-        from repro.kernels import ops as K
-
         if not K.on_tpu():
             warnings.warn(
                 "use_pallas=True off-TPU runs kernels in interpret mode; "
@@ -273,7 +266,7 @@ def compose_pair(a_bits: np.ndarray, b_bits: np.ndarray, n_mid: int,
     ``use_pallas=None`` lets :func:`repro.kernels.ops.bitmatmul` apply its
     kernel-launch guard (Pallas on TPU, jnp oracle elsewhere).
     """
-    from repro.kernels import ops as K  # late import: keeps numpy-only paths jax-free
+    from repro.kernels import ops as K
 
     return np.asarray(K.bitmatmul(a_bits, b_bits, use_pallas=use_pallas))
 

@@ -172,8 +172,8 @@ def maybe_load_calibration() -> Constants:
     ``~/.cache/repro/calibration.json``); entries are keyed by device kind
     (see :mod:`repro.core.calibrate`).  Checked once per process (re-armed
     by :func:`reset_constants`); with no file, the defaults stay active —
-    routing is bit-for-bit today's.  Never imports jax: host-only sessions
-    stay jax-free.
+    routing is bit-for-bit today's.  Only the entry fitted on this
+    process's device kind applies.
     """
     global _AUTOLOAD_DONE
     if _AUTOLOAD_DONE or _ACTIVE.source != "default":
@@ -183,7 +183,7 @@ def maybe_load_calibration() -> Constants:
         os.path.expanduser("~"), ".cache", "repro", "calibration.json")
     if not os.path.exists(path):
         return _ACTIVE
-    from repro.core.calibrate import load_constants  # lazy: json/numpy only
+    from repro.core.calibrate import load_constants
 
     loaded = load_constants(path)
     if loaded is not None:

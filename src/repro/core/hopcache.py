@@ -14,7 +14,7 @@ Design points:
   planes through :func:`compose_pair` — the :mod:`repro.kernels` bitmatmul,
   Pallas on TPU), or ``structured`` (an IMPLICIT gather: one int32 array
   mapping each destination row to its ≤1 source row, ``None`` for the pure
-  identity).  ``backend="auto"`` (the host default) picks per pair by
+  identity).  ``backend="auto"`` (the default) picks per pair by
   the cost model's density threshold
   (:data:`repro.core.costmodel.DENSITY_THRESHOLD`) and CONVERTS an
   accumulation that densifies past it — a filter-heavy 0.1%-dense path stays
@@ -170,12 +170,13 @@ class ComposedIndex:
         spill=None,
         extend_eager: bool = True,
     ) -> None:
-        # tri-state kernel flag: None -> Pallas iff on TPU (jax-free on
-        # hosts), so the default backend stays "auto" off-TPU bit-for-bit
-        # and becomes all-bitplane where the kernels actually pay off
+        # tri-state kernel flag: None -> Pallas iff on TPU.  The backend
+        # stays density-picked everywhere: structured and sparse relations
+        # keep their compact forms, and only dense entries ride the packed
+        # planes (and, on TPU, the bitmatmul kernel)
         use_pallas = resolve_use_pallas(use_pallas)
         if backend is None:
-            backend = "bitplane" if use_pallas else "auto"
+            backend = "auto"
         if backend not in ("auto", "csr", "bitplane"):
             raise ValueError(f"unknown backend {backend!r}")
         if backend == "csr" and not HAVE_SCIPY:
@@ -864,7 +865,7 @@ class ComposedIndex:
         if entry.backend == "csr":
             return np.asarray(masks.astype(np.float32) @ entry.rel) > 0
         if self.use_pallas:
-            from repro.kernels import ops as K  # late import: host path stays jax-free
+            from repro.kernels import ops as K
 
             words = np.asarray(K.bitplane_probe(pack_bitplane(masks), entry.rel))
         else:

@@ -247,6 +247,7 @@ def fused_walk_record_masks_batch(
     direction: str = "fwd",
     use_pallas: Optional[bool] = None,
     max_plane_bytes: int = 256 << 20,
+    counters: Optional[Dict[str, int]] = None,
 ) -> Optional[np.ndarray]:
     """``(B, n_dst)`` bool answered in ONE kernel launch, or None to fall back.
 
@@ -258,7 +259,9 @@ def fused_walk_record_masks_batch(
     and side entrances fail that audit and return None — the caller falls
     back to the full per-op walker (which sums over all paths).  None is
     also returned when the square-padded plane stack the fused kernel
-    streams would exceed ``max_plane_bytes``.
+    streams would exceed ``max_plane_bytes``; that refusal is counted in
+    ``counters["fused_walk_oversize"]`` when a counter dict is given, so a
+    walk routed to the host for size is visible.
 
     ``direction="bwd"`` probes ``src`` (the downstream end) and answers at
     ``dst`` through the transposed planes of the reversed chain; forward
@@ -306,6 +309,8 @@ def fused_walk_record_masks_batch(
         max(op.tensor.n_in[slot], op.tensor.n_out) for op, slot in chain
     )
     if len(chain) * n_max * n_max // 8 > max_plane_bytes:
+        if counters is not None:
+            counters["fused_walk_oversize"] += 1
         return None
 
     if direction == "fwd":

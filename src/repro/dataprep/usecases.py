@@ -10,7 +10,9 @@ Data content is synthetic (the originals are external downloads; offline
 container), but the OPERATION MIX matches the published pipelines: impute /
 normalize / onehot for German-credit-style categorical expansion, filtering +
 column pruning for Compas, heavy one-hot expansion for Census.  The TPC-DI
-generator reproduces Table XI's join cardinalities per scale factor.
+generator reproduces Table XI's join cardinalities per scale factor.  The
+deep chain is a linear many-op pipeline over one table, the shape the fused
+K-hop walk and multi-hop composition are measured on.
 """
 from __future__ import annotations
 
@@ -31,6 +33,9 @@ __all__ = [
     "run_compas",
     "run_census",
     "make_tpcdi_join_inputs",
+    "make_chain_table",
+    "chain_step",
+    "build_deep_chain",
     "USECASES",
     "TPCDI_SCALES",
 ]
@@ -144,3 +149,42 @@ def make_tpcdi_join_inputs(scale: int, seed: int = 7, n_attrs: int = 8) -> Tuple
         lcols[f"l{j}"] = rng.normal(size=n_l).astype(np.float32)
         rcols[f"r{j}"] = rng.normal(size=n_r).astype(np.float32)
     return Table.from_columns(lcols), Table.from_columns(rcols)
+
+
+# ---------------------------------------------------------------------------
+# Deep linear chain (multi-hop composition / fused-walk workload)
+# ---------------------------------------------------------------------------
+def chain_step(d: TrackedTable, i: int) -> TrackedTable:
+    """Op ``i`` of the deterministic deep chain (replayable: the same step
+    sequence builds a merged AND a federated variant identically)."""
+    kind = i % 4
+    if kind == 0:
+        return d.value_transform("x", "scale", factor=1.01)
+    if kind == 1:
+        mask = np.ones(d.table.n_rows, dtype=bool)
+        mask[i :: 17] = False                         # drop a sliver per hop
+        return d.filter_rows(mask)
+    if kind == 2:
+        return d.normalize(["x"], kind="zscore")
+    return d.oversample(frac=0.05, seed=i)
+
+
+def make_chain_table(seed: int, n: int) -> Table:
+    rng = np.random.default_rng(seed)
+    return Table.from_columns({
+        "k": rng.integers(0, n // 2, n).astype(np.float32),
+        "x": rng.normal(size=n).astype(np.float32),
+        "g": rng.integers(0, 4, n).astype(np.float32),
+    })
+
+
+def build_deep_chain(seed: int = 0, n: int = 4000,
+                     n_ops: int = 12) -> Tuple[ProvenanceIndex, str]:
+    """A linear ``n_ops``-op chain over an ``n``-row table; returns the
+    index and the sink dataset id (the source is ``"chain_src"``)."""
+    idx = ProvenanceIndex("deep-chain")
+    d = track(make_chain_table(seed, n), idx, "chain_src")
+    for i in range(n_ops):
+        d = chain_step(d, i)
+    d.mark_sink()
+    return idx, d.dataset_id

@@ -12,9 +12,14 @@ general than the paper's scalar traversal — emitting a padded (Q, max_deg)
 neighbor table (-1 padding).  ``col_idx`` must be padded by ``max_deg``
 trailing sentinels so the dynamic contiguous slice never reads OOB.
 
-TPU notes: each query issues one dynamic-slice of length ``max_deg`` from
-VMEM (lane-aligned when max_deg % 128 == 0), so the inner loop is a vector
-load + compare + select — no scatter, no ragged addressing.
+TPU notes: a probe's segment starts at an arbitrary lane, and the TPU
+loads only 128-lane-aligned windows.  Each query therefore loads the
+aligned window ``[base, base + max_deg + 128)`` around its segment and
+rotates it left by ``start - base`` (one lane rotate), so the inner loop is
+an aligned vector load + rotate + compare + select — no scatter, no
+ragged addressing.  ``max_deg`` must be a multiple of 128 on TPU, and
+``col_idx`` must hold ``max_deg + 128`` trailing sentinels past its last
+aligned window.
 """
 from __future__ import annotations
 
@@ -25,23 +30,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both installs.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["lineage_gather_kernel", "lineage_gather_pallas"]
 
 
-def lineage_gather_kernel(queries_ref, row_ptr_ref, col_idx_ref, out_ref, *, block_q: int, max_deg: int):
+def lineage_gather_kernel(queries_ref, row_ptr_ref, col_idx_ref, out_ref, *,
+                          block_q: int, max_deg: int):
     """Probe ``block_q`` queries against the full CSR resident in VMEM."""
+    width = max_deg + 128
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, max_deg), 1)
 
     def body(qi, _):
         q = queries_ref[qi]
-        start = pl.load(row_ptr_ref, (pl.dslice(q, 1),))[0]
-        end = pl.load(row_ptr_ref, (pl.dslice(q + 1, 1),))[0]
-        seg = pl.load(col_idx_ref, (pl.dslice(start, max_deg),))  # (max_deg,)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (max_deg,), 0)
-        padded = jnp.where(lane < (end - start), seg, jnp.int32(-1))
-        pl.store(out_ref, (pl.dslice(qi, 1), pl.dslice(0, max_deg)), padded[None, :])
+        start = row_ptr_ref[q]
+        end = row_ptr_ref[q + 1]
+        base = pl.multiple_of((start // 128) * 128, 128)
+        window = col_idx_ref[:, pl.ds(base, width)]  # (1, max_deg + 128)
+        seg = pltpu.roll(window, (width - (start - base)) % width, 1)
+        out_ref[pl.ds(qi, 1), :] = jnp.where(lane < (end - start),
+                                             seg[:, :max_deg], jnp.int32(-1))
         return 0
 
     jax.lax.fori_loop(0, block_q, body, 0)
@@ -51,7 +57,7 @@ def lineage_gather_kernel(queries_ref, row_ptr_ref, col_idx_ref, out_ref, *, blo
 def lineage_gather_pallas(
     queries: jax.Array,   # (Q,) int32, Q % block_q == 0
     row_ptr: jax.Array,   # (R+1,) int32
-    col_idx: jax.Array,   # (NNZ + max_deg,) int32 — sentinel-padded
+    col_idx: jax.Array,   # (1, L) int32 — sentinel-padded, see module doc
     *,
     max_deg: int,
     block_q: int = 128,
@@ -64,12 +70,16 @@ def lineage_gather_pallas(
         functools.partial(lineage_gather_kernel, block_q=block_q, max_deg=max_deg),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-            pl.BlockSpec(row_ptr.shape, lambda i: (0,)),   # full CSR in VMEM
-            pl.BlockSpec(col_idx.shape, lambda i: (0,)),
+            # scalars the probe loop indexes by: SMEM
+            pl.BlockSpec((block_q,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(row_ptr.shape, lambda i: (0,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(col_idx.shape, lambda i: (0, 0)),  # CSR in VMEM
         ],
         out_specs=pl.BlockSpec((block_q, max_deg), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q, max_deg), jnp.int32),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(queries, row_ptr, col_idx)

@@ -1,18 +1,19 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These handle padding to hardware-aligned block shapes, choose interpret mode
-automatically off-TPU (this container is CPU-only; TPU v5e is the TARGET),
-and unpad results.  All call sites in :mod:`repro.core` go through here.
+automatically off-TPU, and unpad results.  All call sites in
+:mod:`repro.core` go through here.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.bitmatmul import bitmatmul_pallas
+from repro.kernels.bitmatmul import bit_major_rows, bitmatmul_pallas
 from repro.kernels.lineage_gather import lineage_gather_pallas
 from repro.kernels.bitset_rank import bitset_rank_pallas
 from repro.kernels.batched_walk import batched_walk_pallas
@@ -27,11 +28,15 @@ __all__ = [
     "batched_walk_unfused",
     "on_tpu",
     "launch_counts",
+    "pallas_launch_counts",
     "reset_launch_counts",
 ]
 
 
+@functools.cache
 def on_tpu() -> bool:
+    """Whether this process runs on a TPU — the one routing decision every
+    ``use_pallas=None`` / ``fused_walk=None`` default resolves from."""
     return jax.default_backend() == "tpu"
 
 
@@ -39,11 +44,16 @@ def on_tpu() -> bool:
 # Every public kernel entry counts ONE device dispatch (the Pallas launch on
 # TPU, the equivalent jit'd oracle call elsewhere).  bench_compose_roofline
 # asserts the fused walk's K×3 -> 1 launch reduction off these counters.
+# Compiled (not interpreted) Pallas launches are counted again on their own,
+# so a run can show that the device kernels, not their oracles, answered.
 _LAUNCHES: dict = {}
+_PALLAS_LAUNCHES: dict = {}
 
 
-def _note_launch(name: str) -> None:
+def _note_launch(name: str, compiled_pallas: bool = False) -> None:
     _LAUNCHES[name] = _LAUNCHES.get(name, 0) + 1
+    if compiled_pallas:
+        _PALLAS_LAUNCHES[name] = _PALLAS_LAUNCHES.get(name, 0) + 1
 
 
 def launch_counts() -> dict:
@@ -51,8 +61,14 @@ def launch_counts() -> dict:
     return dict(_LAUNCHES)
 
 
+def pallas_launch_counts() -> dict:
+    """{kernel entry: compiled Pallas launch count} since the last reset."""
+    return dict(_PALLAS_LAUNCHES)
+
+
 def reset_launch_counts() -> None:
     _LAUNCHES.clear()
+    _PALLAS_LAUNCHES.clear()
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
@@ -65,13 +81,55 @@ def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _as_i32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
+    return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+
+
+def _lane(interpret: bool) -> int:
+    # the TPU tiles the last block dim by 128 lanes; the interpreter has no
+    # tiling, so small parity shapes pad only to a sublane multiple
+    return 8 if interpret else 128
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_m", "block_nw", "block_k", "interpret")
+)
+def _bitmatmul_kernel_call(a_bits, b_bits, *, block_m, block_nw, block_k,
+                           interpret):
+    m, kw = a_bits.shape
+    nw = b_bits.shape[1]
+    lane = _lane(interpret)
+    kw_p = _round_up(kw, lane)
+    nw_p = _round_up(nw, lane)
+    bm = min(block_m, _round_up(m, 8))
+    a_p = _pad2(_as_i32(a_bits), _round_up(m, bm), kw_p)
+    b_p = bit_major_rows(_pad2(_as_i32(b_bits), 32 * kw_p, nw_p))
+    out = bitmatmul_pallas(
+        a_p,
+        b_p,
+        block_m=bm,
+        block_nw=math.gcd(block_nw, nw_p),
+        block_k=math.gcd(block_k, kw_p),
+        interpret=interpret,
+    )
+    return jax.lax.bitcast_convert_type(out[:m, :nw], jnp.uint32)
+
+
 def bitmatmul(
     a_bits,
     b_bits,
     *,
-    block_m: int = 8,
+    block_m: int = 256,
     block_nw: int = 128,
-    block_k: int = 256,
+    block_k: int = 512,
     interpret: bool | None = None,
     use_pallas: bool | None = True,
 ):
@@ -83,35 +141,25 @@ def bitmatmul(
     ``use_pallas=None`` resolves automatically — the cost model's
     kernel-launch guard: the Pallas kernel on TPU, the oracle elsewhere
     (interpret-mode emulation is never the cheaper backend on host).
+    ``block_k`` counts words of A contracted per grid step.
     """
-    _note_launch("bitmatmul")
     a_bits = jnp.asarray(a_bits, dtype=jnp.uint32)
     b_bits = jnp.asarray(b_bits, dtype=jnp.uint32)
-    m, kw = a_bits.shape
-    k, nw = b_bits.shape
+    kw = a_bits.shape[1]
+    k = b_bits.shape[0]
     if use_pallas is None:
         use_pallas = on_tpu()
     if not ((kw - 1) * 32 < k <= kw * 32):
         raise ValueError(f"contraction mismatch: A packs {kw * 32} cols, B has {k} rows")
-    # Zero-pad B's contraction rows up to A's packed width (zero rows are inert).
-    b_bits = _pad_to(b_bits, 0, 32) if k % 32 else b_bits
     if interpret is None:
         interpret = not on_tpu()
+    _note_launch("bitmatmul", use_pallas and not interpret)
     if not use_pallas:
-        return ref.bitmatmul_ref(a_bits, b_bits)
-
-    # Pad every dim to its block multiple (zero bits contribute nothing).
-    a_p = _pad_to(_pad_to(a_bits, 0, block_m), 1, block_k // 32)
-    b_p = _pad_to(_pad_to(b_bits, 0, block_k), 1, block_nw)
-    out = bitmatmul_pallas(
-        a_p,
-        b_p,
-        block_m=block_m,
-        block_nw=block_nw,
-        block_k=block_k,
-        interpret=interpret,
-    )
-    return out[:m, :nw]
+        # Zero-pad B's contraction rows up to A's packed width (inert rows).
+        return ref.bitmatmul_ref(a_bits, _pad_to(b_bits, 0, 32))
+    return _bitmatmul_kernel_call(a_bits, b_bits, block_m=block_m,
+                                  block_nw=block_nw, block_k=block_k,
+                                  interpret=interpret)
 
 
 def bitplane_probe(mask_bits, plane_bits, *, use_pallas: bool | None = True, **kw):
@@ -140,7 +188,6 @@ def lineage_gather(
     ``use_pallas=None`` applies the same kernel-launch guard as
     :func:`bitmatmul`: Pallas on TPU, the jnp oracle elsewhere.
     """
-    _note_launch("lineage_gather")
     row_ptr = jnp.asarray(row_ptr, dtype=jnp.int32)
     col_idx = jnp.asarray(col_idx, dtype=jnp.int32)
     queries = jnp.asarray(queries, dtype=jnp.int32)
@@ -148,17 +195,21 @@ def lineage_gather(
         use_pallas = on_tpu()
     if interpret is None:
         interpret = not on_tpu()
+    _note_launch("lineage_gather", use_pallas and not interpret)
     q = queries.shape[0]
     md = max(int(max_deg), 1)
     # Sentinel-pad col_idx so the dynamic slice never reads OOB.
     col_p = jnp.concatenate([col_idx, jnp.full((md,), -1, jnp.int32)])
     if not use_pallas:
         return ref.lineage_gather_ref(queries, row_ptr, col_p, max_deg=md)
-    md_pad = -(-md // 128) * 128 if md > 8 else md  # lane-align when big
-    col_p = jnp.concatenate([col_idx, jnp.full((md_pad,), -1, jnp.int32)])
+    md_pad = _round_up(md, 128)
+    # sentinels cover the last query's aligned window: see lineage_gather.py
+    tail = _round_up(col_idx.shape[0], 128) - col_idx.shape[0] + md_pad + 128
+    col_p = jnp.concatenate([col_idx, jnp.full((tail,), -1, jnp.int32)])
     q_p = _pad_to(queries, 0, block_q)
     out = lineage_gather_pallas(
-        q_p, row_ptr, col_p, max_deg=md_pad, block_q=block_q, interpret=interpret
+        q_p, row_ptr, col_p[None, :], max_deg=md_pad, block_q=block_q,
+        interpret=interpret
     )
     return out[:q, :md]
 
@@ -176,20 +227,23 @@ def bitset_rank(
     ``use_pallas=None`` applies the same kernel-launch guard as
     :func:`bitmatmul`: Pallas on TPU, the jnp oracle elsewhere.
     """
-    _note_launch("bitset_rank")
     words = jnp.asarray(words, dtype=jnp.uint32)
     positions = jnp.asarray(positions, dtype=jnp.int32)
     if use_pallas is None:
         use_pallas = on_tpu()
     if interpret is None:
         interpret = not on_tpu()
+    _note_launch("bitset_rank", use_pallas and not interpret)
     if not use_pallas:
         return ref.bitset_rank_ref(words, positions)
     q = positions.shape[0]
-    # -1 pads resolve to rank 0 in-kernel via the pos<0 guard.
-    p_p = _pad_to(positions, 0, block_q, value=0)
-    out = bitset_rank_pallas(words, p_p, block_q=block_q, interpret=interpret)
-    return out[:q]
+    w = _round_up(max(words.shape[0], 1), _lane(interpret))
+    chunk = math.gcd(512, w)
+    words_p = _pad2(_as_i32(words)[None, :], 1, w)
+    p_p = _pad_to(positions, 0, block_q, value=-1)[:, None]
+    out = bitset_rank_pallas(words_p, p_p, block_q=block_q, chunk=chunk,
+                             interpret=interpret)
+    return out[:q, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -212,12 +266,35 @@ def _check_walk_chain(mask_bits, planes) -> None:
         kw = plane.shape[1]
 
 
+@functools.partial(
+    jax.jit, static_argnames=("block_b", "block_k", "block_nw", "interpret")
+)
+def _batched_walk_kernel_call(mask_bits, *planes, block_b, block_k, block_nw,
+                              interpret):
+    # One common padded dim: every hop's rows AND packed cols fit inside it.
+    words = max([mask_bits.shape[1]]
+                + [max(-(-p.shape[0] // 32), p.shape[1]) for p in planes])
+    nw = _round_up(words, _lane(interpret))
+    b = mask_bits.shape[0]
+    bb = min(block_b, _round_up(b, 8))
+    mask_p = _pad2(_as_i32(mask_bits), _round_up(b, bb), nw)
+    stacked = jnp.stack(
+        [bit_major_rows(_pad2(_as_i32(p), 32 * nw, nw)) for p in planes])
+    out_p, counts_p = batched_walk_pallas(
+        mask_p, stacked, block_b=bb, block_k=math.gcd(block_k, nw),
+        block_nw=math.gcd(block_nw, nw), interpret=interpret,
+    )
+    out = out_p[:b, : planes[-1].shape[1]]
+    return jax.lax.bitcast_convert_type(out, jnp.uint32), counts_p[:, :b, 0]
+
+
 def batched_walk(
     mask_bits,
     planes,
     *,
-    block_b: int = 8,
-    block_k: int = 256,
+    block_b: int = 128,
+    block_k: int = 512,
+    block_nw: int = 128,
     interpret: bool | None = None,
     use_pallas: bool | None = None,
 ):
@@ -235,7 +312,6 @@ def batched_walk(
     common square dim (inert under (OR, AND)) and the planes stack into a
     single streamed operand; see :mod:`repro.kernels.batched_walk`.
     """
-    _note_launch("batched_walk")
     mask_bits = jnp.asarray(mask_bits, dtype=jnp.uint32)
     planes = [jnp.asarray(p, dtype=jnp.uint32) for p in planes]
     if not planes:
@@ -245,28 +321,12 @@ def batched_walk(
         use_pallas = on_tpu()
     if interpret is None:
         interpret = not on_tpu()
-    b = mask_bits.shape[0]
-    k = len(planes)
-    out_w = planes[-1].shape[1]
+    _note_launch("batched_walk", use_pallas and not interpret)
     if not use_pallas:
-        out, counts = _batched_walk_oracle(mask_bits, *planes, n_hops=k)
-        return out, counts
-
-    # One common padded dim: every hop's rows AND packed cols fit inside it.
-    n_pad = 32  # at least one word
-    for p in planes:
-        n_pad = max(n_pad, p.shape[0], p.shape[1] * 32)
-    n_pad = max(n_pad, mask_bits.shape[1] * 32)
-    n_pad = -(-n_pad // block_k) * block_k
-    nw = n_pad // 32
-    mask_p = _pad_to(_pad_to(mask_bits, 0, block_b), 1, nw)
-    stacked = jnp.zeros((k, n_pad, nw), dtype=jnp.uint32)
-    for j, p in enumerate(planes):
-        stacked = stacked.at[j, : p.shape[0], : p.shape[1]].set(p)
-    out_p, counts_p = batched_walk_pallas(
-        mask_p, stacked, block_b=block_b, block_k=block_k, interpret=interpret
-    )
-    return out_p[:b, :out_w], counts_p[:, :b]
+        return _batched_walk_oracle(mask_bits, *planes, n_hops=len(planes))
+    return _batched_walk_kernel_call(mask_bits, *planes, block_b=block_b,
+                                     block_k=block_k, block_nw=block_nw,
+                                     interpret=interpret)
 
 
 def batched_walk_unfused(

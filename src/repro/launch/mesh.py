@@ -7,40 +7,34 @@ see the 1 real CPU device).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6 names explicit/auto axis types; older releases have neither
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
     "make_local_mesh",
-    "make_mesh_compat",
+    "make_mesh",
     "make_shard_mesh",
     "host_device_count",
     "request_host_devices",
 ]
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the install supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; (2, 16, 16) = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """All locally visible devices on ('data',) — tests and examples."""
     n = len(jax.devices())
-    return make_mesh_compat((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def host_device_count() -> int:

@@ -20,7 +20,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 __all__ = ["set_policy", "policy", "hint", "use_policy"]
 
@@ -48,26 +48,12 @@ def use_policy(mapping: Optional[Dict[str, Axes]]):
         set_policy(prev)
 
 
-def _current_mesh():
-    """The ambient mesh: ``get_abstract_mesh`` on current jax, the legacy
-    with-Mesh thread resource on older releases (same axis_names/shape
-    surface for the uses below)."""
-    try:
-        from jax.sharding import get_abstract_mesh
-
-        return get_abstract_mesh()
-    except ImportError:  # pragma: no cover - version-dependent
-        from jax.interpreters.pxla import thread_resources
-
-        return thread_resources.env.physical_mesh
-
-
 def hint(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     """Constrain ``x``'s sharding; dims named None stay unconstrained."""
     pol = policy()
     if pol is None:
         return x
-    mesh = _current_mesh()
+    mesh = get_abstract_mesh()
     if not mesh.axis_names:          # policy set but no mesh (local runs)
         return x
     if len(logical) != x.ndim:

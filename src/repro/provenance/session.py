@@ -114,8 +114,7 @@ class QuerySession:
         self.index = index
         self.composed = composed if composed is not None else index.composed()
         self.use_hopcache = use_hopcache
-        # tri-state like use_pallas: None -> fused kernel walk iff on TPU
-        # (keeps host routing bit-for-bit and numpy-only paths jax-free);
+        # tri-state like use_pallas: None -> fused kernel walk iff on TPU;
         # True forces it everywhere (the parity-test path), False disables
         self.fused_walk = fused_walk
         if hopcache_min_batch is not None:
@@ -136,6 +135,8 @@ class QuerySession:
             "walk": 0,
             "hopcache": 0,
             "fused_walk": 0,
+            # linear chains the fused kernel declined for size (host walk)
+            "fused_walk_oversize": 0,
             "meta": 0,
             "fused_groups": 0,
             "fused_plans": 0,
@@ -247,13 +248,9 @@ class QuerySession:
 
     def _fused_walk_on(self) -> bool:
         """Resolve the tri-state ``fused_walk`` flag; the None default means
-        "fused kernel iff on TPU" and never imports jax on hosts."""
+        "fused kernel iff on TPU"."""
         if self.fused_walk is not None:
             return bool(self.fused_walk)
-        import sys
-
-        if "jax" not in sys.modules:
-            return False
         from repro.kernels import ops as K
 
         return K.on_tpu()
@@ -356,7 +353,7 @@ class QuerySession:
         if self._fused_walk_on():
             fused = Q.fused_walk_record_masks_batch(
                 self.index, plan.source, plan.target, plan.rows,
-                plan.direction,
+                plan.direction, counters=self.counters,
             )
             if fused is not None:  # non-linear chains fall through to the walk
                 self.counters["fused_walk"] += 1

@@ -106,19 +106,10 @@ class _CollectiveEngine:
     def _shard_map(fn, **kwargs):
         # The outputs are replicated BY CONSTRUCTION (all_gather / psum),
         # but the static replication checker cannot see through the
-        # scatter ops, so it must be disabled; its keyword has been
-        # renamed across jax releases.
+        # scatter ops, so it must be disabled.
         import jax
 
-        smap = jax.shard_map if hasattr(jax, "shard_map") else None
-        if smap is None:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map as smap
-        for flag in ("check_vma", "check_rep"):
-            try:
-                return smap(fn, **kwargs, **{flag: False})
-            except TypeError:
-                continue
-        return smap(fn, **kwargs)  # pragma: no cover
+        return jax.shard_map(fn, **kwargs, check_vma=False)
 
     def _padded(self, st: "ShardedTensor", inp: int):
         """(out_idx, in_idx, valid) int32/bool ``(S, L)`` blocks + widths."""
@@ -628,13 +619,10 @@ class ShardedProvenanceIndex:
             return "numpy", None
         if engine not in ("auto", "collective"):
             raise ValueError(f"unknown engine {engine!r}")
-        try:
-            if mesh is None:
-                from repro.launch.mesh import make_shard_mesh
+        if mesh is None:
+            from repro.launch.mesh import make_shard_mesh
 
-                mesh = make_shard_mesh(self.n_shards)
-        except Exception:  # jax missing/broken: the view still works
-            mesh = None
+            mesh = make_shard_mesh(self.n_shards)
         if mesh is None:
             if engine == "collective":
                 raise RuntimeError(

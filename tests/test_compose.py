@@ -70,7 +70,7 @@ def test_sharded_compose_and_audit_match_local():
         compose_sharded, lineage_audit_sharded, backward_frontier_sharded,
         shard_relation)
     from repro.kernels.ref import pack_bits, unpack_bits
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     idx, to = _pipeline(1, n=48)
     sink = to.dataset_id
@@ -78,7 +78,7 @@ def test_sharded_compose_and_audit_match_local():
     n_dst = idx.datasets[sink].n_rows
     rel = dataset_lineage(idx, "src", sink, use_pallas=False)
 
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     bits = np.asarray(pack_bits(jnp.asarray(rel)))
     rb = shard_relation(bits, mesh)
 

@@ -18,7 +18,7 @@ import pytest
 
 from repro.configs.registry import get_smoke_config
 from repro.launch import sharding as SD
-from repro.launch.mesh import host_device_count, make_mesh_compat
+from repro.launch.mesh import host_device_count, make_mesh
 from repro.models import pshard as PS
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import init_train_state, make_train_step
@@ -37,7 +37,7 @@ def mesh(request):
     if host_device_count() < need:
         pytest.skip(f"mesh {shape} needs {need} devices, "
                     f"have {host_device_count()}")
-    return make_mesh_compat(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _active(mesh):

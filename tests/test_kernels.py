@@ -7,20 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:  # hypothesis is a dev extra: only the property tests skip without it
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - environment-dependent
-    def given(*_a, **_k):
-        return lambda f: pytest.mark.skip(reason="hypothesis not installed")(f)
-
-    def settings(*_a, **_k):
-        return lambda f: f
-
-    class _NullStrategies:
-        def __getattr__(self, _name):
-            return lambda *a, **k: None
-
-    st = _NullStrategies()
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops as K
 from repro.kernels import ref as R

@@ -11,9 +11,6 @@ the invariants hold for ANY data-preparation pipeline:
       memory claim in its asymptotic form)
 """
 import numpy as np
-import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import query as Q

@@ -1,8 +1,5 @@
 """Unit + property tests for bitset schema metadata and attribute maps."""
 import numpy as np
-import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import schema as sc

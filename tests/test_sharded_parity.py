@@ -22,6 +22,7 @@ exposes enough devices (CI's multi-device lane forces 8 via
 """
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 import pipegen
 import test_query_parity as tqp
@@ -331,58 +332,36 @@ def test_collective_engine_parity(seed, n_shards):
 
 # ===========================================================================
 # Hypothesis properties (free seed x shards x probes, minimized on failure).
-# Guarded, NOT importorskip'd at module level: the seeded differential sweep
-# above must always run even where hypothesis is not installed.
 # ===========================================================================
-try:
-    from hypothesis import given, settings, strategies as st
+@settings(max_examples=25, deadline=None)
+@given(seed=st.integers(0, 10_000), n_shards=st.integers(1, 12),
+       data=st.data())
+def test_prop_record_parity(seed, n_shards, data):
+    idx, sink, _ = pipegen.random_pipeline(seed, name="hyp")
+    merged = idx.session()
+    n_src = idx.datasets["src"].n_rows
+    probes = data.draw(st.lists(
+        st.lists(st.integers(0, n_src - 1), max_size=6), max_size=4))
+    sv = ShardedProvenanceIndex(idx, n_shards, engine="numpy")
+    ss = sv.session()
+    plan_m = (prov(idx).source("src").rows_batch(probes)
+              .forward().to(sink).plan())
+    plan_s = (prov(sv).source("src").rows_batch(probes)
+              .forward().to(sink).plan())
+    _mask_stacks_equal(ss.run_masks(plan_s), merged.run_masks(plan_m))
 
-    HAVE_HYPOTHESIS = True
-except ImportError:       # pragma: no cover - environment-dependent
-    HAVE_HYPOTHESIS = False
 
-if HAVE_HYPOTHESIS:
-
-    @settings(max_examples=25, deadline=None)
-    @given(seed=st.integers(0, 10_000), n_shards=st.integers(1, 12),
-           data=st.data())
-    def test_prop_record_parity(seed, n_shards, data):
-        idx, sink, _ = pipegen.random_pipeline(seed, name="hyp")
-        merged = idx.session()
-        n_src = idx.datasets["src"].n_rows
-        probes = data.draw(st.lists(
-            st.lists(st.integers(0, n_src - 1), max_size=6), max_size=4))
-        sv = ShardedProvenanceIndex(idx, n_shards, engine="numpy")
-        ss = sv.session()
-        plan_m = (prov(idx).source("src").rows_batch(probes)
-                  .forward().to(sink).plan())
-        plan_s = (prov(sv).source("src").rows_batch(probes)
-                  .forward().to(sink).plan())
-        _mask_stacks_equal(ss.run_masks(plan_s), merged.run_masks(plan_m))
-
-    @settings(max_examples=15, deadline=None)
-    @given(seed=st.integers(0, 10_000), n_shards=st.integers(2, 10))
-    def test_prop_diamond_backward_parity(seed, n_shards):
-        idx, sink = pipegen.diamond_pipeline(seed % 50, name="hypdia")
-        merged = idx.session()
-        n_sink = idx.datasets[sink].n_rows
-        probes = [[], [0], list(range(n_sink))]
-        sv = ShardedProvenanceIndex(idx, n_shards, engine="numpy")
-        plan_m = (prov(idx).source(sink).rows_batch(probes)
-                  .backward().to("src").plan())
-        plan_s = (prov(sv).source(sink).rows_batch(probes)
-                  .backward().to("src").plan())
-        _mask_stacks_equal(sv.session().run_masks(plan_s),
-                           merged.run_masks(plan_m))
-
-else:
-
-    @pytest.mark.skip(reason="hypothesis not installed; seeded sweep covers "
-                             "the property space")
-    def test_prop_record_parity():
-        pass
-
-    @pytest.mark.skip(reason="hypothesis not installed; seeded sweep covers "
-                             "the property space")
-    def test_prop_diamond_backward_parity():
-        pass
+@settings(max_examples=15, deadline=None)
+@given(seed=st.integers(0, 10_000), n_shards=st.integers(2, 10))
+def test_prop_diamond_backward_parity(seed, n_shards):
+    idx, sink = pipegen.diamond_pipeline(seed % 50, name="hypdia")
+    merged = idx.session()
+    n_sink = idx.datasets[sink].n_rows
+    probes = [[], [0], list(range(n_sink))]
+    sv = ShardedProvenanceIndex(idx, n_shards, engine="numpy")
+    plan_m = (prov(idx).source(sink).rows_batch(probes)
+              .backward().to("src").plan())
+    plan_s = (prov(sv).source(sink).rows_batch(probes)
+              .backward().to("src").plan())
+    _mask_stacks_equal(sv.session().run_masks(plan_s),
+                       merged.run_masks(plan_m))

@@ -7,11 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # pragma: no cover - version-dependent
-    pytest.skip("jax.sharding.AxisType unavailable on this JAX",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs.registry import ARCHS, get_config
 from repro.launch import sharding as SD

@@ -96,11 +96,11 @@ def test_crash_and_resume_exact(tmp_path, setup):
 def test_elastic_restore_new_sharding(tmp_path, setup):
     """A checkpoint restores onto a different mesh/sharding (elastic)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     cfg, opt, state, step, tp = setup
     ckpt = CheckpointManager(str(tmp_path / "ck3"), keep=1)
     ckpt.save(1, state.params, blocking=True)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), state.params)
     restored = ckpt.restore(1, like=state.params, shardings=shardings)
     leaf = jax.tree.leaves(restored)[0]
