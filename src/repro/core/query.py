@@ -45,6 +45,7 @@ import dataclasses
 import warnings
 from typing import Dict, List, Optional, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.opcat import AttrMap
@@ -55,6 +56,7 @@ from repro.core.provtensor import (
     pack_mask,
     unpack_bitplane,
 )
+from repro.core.spans import span
 
 __all__ = [
     "Hop",
@@ -269,7 +271,22 @@ def fused_walk_record_masks_batch(
     the corresponding full walker.  ``use_pallas=None`` is the
     kernel-launch guard: the fused Pallas kernel on TPU, the one-dispatch
     jnp oracle elsewhere.
+
+    The walk runs in the span ``walk.fused``, with the host-to-device copy
+    of the packed mask and the planes in ``walk.put`` and the wait for the
+    answer in ``walk.wait`` (:mod:`repro.core.spans`); with a counter dict
+    their nanoseconds accrue to ``fused_walk_ns``, ``walk_put_ns`` and
+    ``walk_wait_ns``, and the bytes put to ``walk_put_bytes``.
     """
+    with span("walk.fused", counters, "fused_walk_ns"):
+        return _fused_walk(index, src, dst, rows_batch, direction, use_pallas,
+                           max_plane_bytes, counters)
+
+
+def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
+                direction: str, use_pallas: Optional[bool],
+                max_plane_bytes: int,
+                counters: Optional[Dict[str, int]]) -> Optional[np.ndarray]:
     from repro.core.compose import path_tensors
 
     up, down = (src, dst) if direction == "fwd" else (dst, src)
@@ -321,8 +338,19 @@ def fused_walk_record_masks_batch(
     from repro.kernels import ops as K
 
     mask_bits = pack_bitplane(np.ascontiguousarray(stack))
-    out_bits, _counts = K.batched_walk(mask_bits, planes, use_pallas=use_pallas)
-    return unpack_bitplane(np.asarray(out_bits), index.datasets[dst].n_rows)
+    with span("walk.put", counters, "walk_put_ns"):
+        # one device_put per operand: the copies ops.batched_walk would
+        # make itself (its jnp.asarray then passes them through)
+        mask_dev = jax.device_put(mask_bits)
+        planes_dev = [jax.device_put(p) for p in planes]
+    if counters is not None:
+        counters["walk_put_bytes"] += mask_bits.nbytes + sum(
+            p.nbytes for p in planes)
+    out_bits, _counts = K.batched_walk(mask_dev, planes_dev,
+                                       use_pallas=use_pallas)
+    with span("walk.wait", counters, "walk_wait_ns"):
+        out_host = np.asarray(out_bits)
+    return unpack_bitplane(out_host, index.datasets[dst].n_rows)
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import query as Q
+from repro.core.spans import span
 from repro.provenance.plan import QueryPlan
 
 __all__ = ["QuerySession", "run_many_fused"]
@@ -140,6 +141,16 @@ class QuerySession:
             "meta": 0,
             "fused_groups": 0,
             "fused_plans": 0,
+            # nanoseconds in each span of the record path (bytes for
+            # walk_put_bytes): session.execute, then inside it walk.fused
+            # (with walk.put and walk.wait), walk.host or hopcache.probe
+            "execute_ns": 0,
+            "fused_walk_ns": 0,
+            "walk_put_ns": 0,
+            "walk_put_bytes": 0,
+            "walk_wait_ns": 0,
+            "host_walk_ns": 0,
+            "hopcache_probe_ns": 0,
         }
 
     # -- planning --------------------------------------------------------------
@@ -285,9 +296,10 @@ class QuerySession:
         if plan.kind != "record" or plan.how:
             raise ValueError("run_masks handles plain record plans only")
         self.counters["plans"] += 1
-        strategy = self._strategy(plan)
-        self.counters[strategy] += 1
-        return self._record_masks(plan, strategy)
+        with span("session.execute", self.counters, "execute_ns"):
+            strategy = self._strategy(plan)
+            self.counters[strategy] += 1
+            return self._record_masks(plan, strategy)
 
     def run_record_terms(self, entry_masks: Dict[str, np.ndarray],
                          direction: str, collect_hops: bool = False):
@@ -327,6 +339,10 @@ class QuerySession:
 
     # -- executors (each returns one payload per probe) -------------------------
     def _execute(self, plan: QueryPlan) -> List:
+        with span("session.execute", self.counters, "execute_ns"):
+            return self._execute_plan(plan)
+
+    def _execute_plan(self, plan: QueryPlan) -> List:
         strategy = self._strategy(plan)
         self.counters[strategy] += 1
         if plan.kind == "record":
@@ -345,11 +361,10 @@ class QuerySession:
         federation's segment hook) answer through this, so routing and
         fallback shapes cannot diverge between the two surfaces."""
         if strategy == "hopcache":
-            if plan.direction == "fwd":
-                return self.composed.probe_forward(
-                    plan.rows, plan.source, plan.target)
-            return self.composed.probe_backward(
-                plan.rows, plan.source, plan.target)
+            probe = (self.composed.probe_forward if plan.direction == "fwd"
+                     else self.composed.probe_backward)
+            with span("hopcache.probe", self.counters, "hopcache_probe_ns"):
+                return probe(plan.rows, plan.source, plan.target)
         if self._fused_walk_on():
             fused = Q.fused_walk_record_masks_batch(
                 self.index, plan.source, plan.target, plan.rows,
@@ -363,7 +378,8 @@ class QuerySession:
             if plan.direction == "fwd"
             else Q.backward_record_masks_batch
         )
-        masks = walker(self.index, plan.source, plan.rows)
+        with span("walk.host", self.counters, "host_walk_ns"):
+            masks = walker(self.index, plan.source, plan.rows)
         return masks.get(
             plan.target,
             np.zeros((plan.n_probes, self.index.datasets[plan.target].n_rows),

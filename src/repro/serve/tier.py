@@ -47,6 +47,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from repro.core.spans import span
 from repro.provenance.plan import QueryPlan
 from repro.serve.admission import AdmissionController, TierClosedError
 
@@ -60,12 +61,13 @@ class _Request:
     ``future`` is EITHER an ``asyncio.Future`` (async ``submit``) or a
     ``concurrent.futures.Future`` (threaded burst submission) — both are
     settled from the loop thread, where asyncio futures require it and
-    concurrent futures are thread-safe anyway."""
+    concurrent futures are thread-safe anyway.  ``t_submit`` is the
+    ``perf_counter_ns`` of admission, where the plan's queue wait starts."""
 
     tenant: str
     plan: QueryPlan
     future: object
-    t_submit: float
+    t_submit: int
 
 
 class ServingTier:
@@ -109,6 +111,14 @@ class ServingTier:
             "flush_drain": 0,
             "convoys": 0,
             "max_batch_seen": 0,
+            # written on the executor thread only (the keys above on the
+            # loop thread): executor passes, the plans they ran, the
+            # nanoseconds of the tier.pass spans, and each plan's wait
+            # from admission to the start of its pass
+            "passes": 0,
+            "pass_plans": 0,
+            "pass_ns": 0,
+            "queue_wait_ns": 0,
         }
         self._buckets: Dict[Tuple, List[_Request]] = {}
         self._timers: Dict[Tuple, "asyncio.TimerHandle"] = {}
@@ -191,7 +201,7 @@ admission.TenantScope`, or an iterable of allowed refs) and an optional
         req = _Request(tenant, plan,
                        self._loop.create_future() if future is None
                        else future,
-                       time.perf_counter())
+                       time.perf_counter_ns())
         key = plan.fuse_key()
         bucket = self._buckets.setdefault(key, [])
         bucket.append(req)
@@ -230,6 +240,19 @@ admission.TenantScope`, or an iterable of allowed refs) and an optional
         for key in list(self._buckets):
             self._flush(key, reason)
 
+    def _run_pass(self, batches: List[List[_Request]]) -> List:
+        """One executor pass (pool thread): ONE ``backend.run_many`` over
+        every plan of ``batches``, in the span ``tier.pass``."""
+        t_start = time.perf_counter_ns()
+        reqs = [r for b in batches for r in b]
+        c = self.counters
+        c["queue_wait_ns"] += sum(t_start - r.t_submit for r in reqs)
+        c["passes"] += 1
+        c["pass_plans"] += len(reqs)
+        with span("tier.pass", c, "pass_ns",
+                  **{"pass": c["passes"], "plans": len(reqs)}):
+            return self.backend.run_many([r.plan for r in reqs])
+
     async def _executor(self) -> None:
         """Drain ready batches: everything already flushed rides ONE
         ``backend.run_many`` call on the (single-threaded) pool — a convoy
@@ -256,10 +279,9 @@ admission.TenantScope`, or an iterable of allowed refs) and an optional
                 batches.append(nxt)
             if len(batches) > 1:
                 self.counters["convoys"] += 1
-            plans = [r.plan for b in batches for r in b]
             try:
                 results = await self._loop.run_in_executor(
-                    self._pool, self.backend.run_many, plans)
+                    self._pool, self._run_pass, batches)
             except Exception as exc:        # noqa: BLE001 — fan the real
                 for b in batches:           # error out to every caller
                     for r in b:
