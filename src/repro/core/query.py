@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
+from collections import OrderedDict
 from typing import Dict, List, Optional, Set, Tuple
 
 import jax
@@ -65,6 +66,8 @@ __all__ = [
     "forward_record_masks_batch",
     "backward_record_masks_batch",
     "fused_walk_record_masks_batch",
+    "WalkPlaneStore",
+    "plane_store_budget",
     "record_masks_terms_batch",
     "attr_propagate_terms_batch",
     "q1_forward",
@@ -241,6 +244,54 @@ def backward_record_masks_batch(
 # ---------------------------------------------------------------------------
 # Fused-kernel record walk (ROADMAP item 4)
 # ---------------------------------------------------------------------------
+def plane_store_budget(max_plane_bytes: int) -> int:
+    """Device bytes a :class:`WalkPlaneStore` may keep: a quarter of the
+    default device's ``bytes_limit``, or ``4 × max_plane_bytes`` where the
+    device reports no memory (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) // 4 if limit else 4 * max_plane_bytes
+
+
+class WalkPlaneStore:
+    """Least-recently-used fused-walk plane operands, resident on the device.
+
+    Keyed by ``(direction, ((op_id, slot), ...))``: the index is
+    append-only and an op's tensor never changes (spilling swaps it for a
+    fault proxy of the same content), so a chain's prepared operand
+    (:func:`repro.kernels.ops.prepare_walk_planes`) stays exact for the
+    index's life.  A store holds the operands of one ``use_pallas``
+    routing.  It keeps its tally in the owner's ``counters``:
+    ``walk_plane_hits``, ``walk_plane_misses``, ``walk_plane_evictions``
+    and ``walk_plane_bytes``, the bytes resident.
+    """
+
+    def __init__(self, counters: Dict[str, int]) -> None:
+        self.counters = counters
+        self._stacks: "OrderedDict[tuple, object]" = OrderedDict()
+
+    def get(self, key: tuple):
+        prepared = self._stacks.get(key)
+        if prepared is None:
+            self.counters["walk_plane_misses"] += 1
+            return None
+        self._stacks.move_to_end(key)
+        self.counters["walk_plane_hits"] += 1
+        return prepared
+
+    def keep(self, key: tuple, prepared, budget: int) -> None:
+        """Keep ``prepared`` under ``budget`` bytes, evicting the least
+        recently used; an operand larger than the budget is not kept."""
+        if prepared.nbytes > budget:
+            return
+        self._stacks[key] = prepared
+        self.counters["walk_plane_bytes"] += prepared.nbytes
+        while self.counters["walk_plane_bytes"] > budget:
+            _, old = self._stacks.popitem(last=False)
+            self.counters["walk_plane_bytes"] -= old.nbytes
+            self.counters["walk_plane_evictions"] += 1
+
+
 def fused_walk_record_masks_batch(
     index: ProvenanceIndex,
     src: str,
@@ -250,6 +301,7 @@ def fused_walk_record_masks_batch(
     use_pallas: Optional[bool] = None,
     max_plane_bytes: int = 256 << 20,
     counters: Optional[Dict[str, int]] = None,
+    planes: Optional[WalkPlaneStore] = None,
 ) -> Optional[np.ndarray]:
     """``(B, n_dst)`` bool answered in ONE kernel launch, or None to fall back.
 
@@ -272,21 +324,27 @@ def fused_walk_record_masks_batch(
     kernel-launch guard: the fused Pallas kernel on TPU, the one-dispatch
     jnp oracle elsewhere.
 
+    With a ``planes`` store, a chain that passed the audit and the cap
+    looks its device operand up there first: on a hit only the packed mask
+    is put on the device; on a miss the planes are put, prepared and kept
+    (:class:`WalkPlaneStore`, budget :func:`plane_store_budget`).
+
     The walk runs in the span ``walk.fused``, with the host-to-device copy
-    of the packed mask and the planes in ``walk.put`` and the wait for the
-    answer in ``walk.wait`` (:mod:`repro.core.spans`); with a counter dict
-    their nanoseconds accrue to ``fused_walk_ns``, ``walk_put_ns`` and
-    ``walk_wait_ns``, and the bytes put to ``walk_put_bytes``.
+    of the packed mask and, on a miss, the planes in ``walk.put`` and the
+    wait for the answer in ``walk.wait`` (:mod:`repro.core.spans`); with a
+    counter dict their nanoseconds accrue to ``fused_walk_ns``,
+    ``walk_put_ns`` and ``walk_wait_ns``, and the bytes put to
+    ``walk_put_bytes``.
     """
     with span("walk.fused", counters, "fused_walk_ns"):
         return _fused_walk(index, src, dst, rows_batch, direction, use_pallas,
-                           max_plane_bytes, counters)
+                           max_plane_bytes, counters, planes)
 
 
 def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
                 direction: str, use_pallas: Optional[bool],
-                max_plane_bytes: int,
-                counters: Optional[Dict[str, int]]) -> Optional[np.ndarray]:
+                max_plane_bytes: int, counters: Optional[Dict[str, int]],
+                planes: Optional[WalkPlaneStore]) -> Optional[np.ndarray]:
     from repro.core.compose import path_tensors
 
     up, down = (src, dst) if direction == "fwd" else (dst, src)
@@ -330,24 +388,30 @@ def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
             counters["fused_walk_oversize"] += 1
         return None
 
-    if direction == "fwd":
-        planes = [op.tensor.bitplane_fwd(slot) for op, slot in chain]
-    else:
-        planes = [op.tensor.bitplane_bwd(slot) for op, slot in reversed(chain)]
-
     from repro.kernels import ops as K
+
+    key = (direction, tuple((op.op_id, slot) for op, slot in chain))
+    prepared = planes.get(key) if planes is not None else None
+    host_planes = []
+    if prepared is None:
+        if direction == "fwd":
+            host_planes = [op.tensor.bitplane_fwd(slot) for op, slot in chain]
+        else:
+            host_planes = [op.tensor.bitplane_bwd(slot)
+                           for op, slot in reversed(chain)]
 
     mask_bits = pack_bitplane(np.ascontiguousarray(stack))
     with span("walk.put", counters, "walk_put_ns"):
-        # one device_put per operand: the copies ops.batched_walk would
-        # make itself (its jnp.asarray then passes them through)
         mask_dev = jax.device_put(mask_bits)
-        planes_dev = [jax.device_put(p) for p in planes]
+        if prepared is None:
+            prepared = K.prepare_walk_planes(
+                [jax.device_put(p) for p in host_planes], use_pallas=use_pallas)
     if counters is not None:
         counters["walk_put_bytes"] += mask_bits.nbytes + sum(
-            p.nbytes for p in planes)
-    out_bits, _counts = K.batched_walk(mask_dev, planes_dev,
-                                       use_pallas=use_pallas)
+            p.nbytes for p in host_planes)
+    if host_planes and planes is not None:
+        planes.keep(key, prepared, plane_store_budget(max_plane_bytes))
+    out_bits, _counts = K.batched_walk_prepared(mask_dev, prepared)
     with span("walk.wait", counters, "walk_wait_ns"):
         out_host = np.asarray(out_bits)
     return unpack_bitplane(out_host, index.datasets[dst].n_rows)

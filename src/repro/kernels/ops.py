@@ -6,6 +6,7 @@ automatically off-TPU, and unpad results.  All call sites in
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -26,6 +27,9 @@ __all__ = [
     "bitset_rank",
     "batched_walk",
     "batched_walk_unfused",
+    "batched_walk_prepared",
+    "prepare_walk_planes",
+    "WalkPlanes",
     "on_tpu",
     "launch_counts",
     "pallas_launch_counts",
@@ -255,37 +259,117 @@ def _batched_walk_oracle(mask_bits, *planes, n_hops: int):
     return ref.batched_walk_ref(mask_bits, planes)
 
 
-def _check_walk_chain(mask_bits, planes) -> None:
-    kw = mask_bits.shape[1]
-    for j, plane in enumerate(planes):
-        rows = plane.shape[0]
+def _check_walk_chain(kw: int, shapes) -> None:
+    """Each hop's plane rows must fit the packed width that enters it:
+    ``kw`` words for hop 0, the previous plane's words after it."""
+    for j, (rows, words) in enumerate(shapes):
         if not ((kw - 1) * 32 < rows <= kw * 32):
             raise ValueError(
                 f"hop {j}: frontier packs {kw * 32} cols, plane has {rows} rows"
             )
-        kw = plane.shape[1]
+        kw = words
+
+
+@dataclasses.dataclass(frozen=True)
+class WalkPlanes:
+    """A fused walk's plane operand on the device, made once by
+    :func:`prepare_walk_planes` and walked by :func:`batched_walk_prepared`
+    any number of times.
+
+    ``operand`` is the padded, bit-major ``(K, 32·nw, nw)`` int32 stack the
+    Pallas kernel streams, or on the oracle path the tuple of per-hop uint32
+    planes.  ``shapes`` holds each hop's packed ``(rows, words)``: the chain
+    check and the answer's width (the last hop's words) read it."""
+
+    operand: object
+    shapes: tuple
+    use_pallas: bool
+    interpret: bool
+
+    @property
+    def nbytes(self) -> int:
+        """Device bytes the operand holds."""
+        return sum(x.nbytes for x in jax.tree_util.tree_leaves(self.operand))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _prepare_walk_stack(*planes, interpret):
+    # One common padded dim: every hop's rows AND packed cols fit inside it
+    # (a checked chain's mask packs ceil(rows_0 / 32) words, so it fits too).
+    words = max(max(-(-p.shape[0] // 32), p.shape[1]) for p in planes)
+    nw = _round_up(words, _lane(interpret))
+    return jnp.stack(
+        [bit_major_rows(_pad2(_as_i32(p), 32 * nw, nw)) for p in planes])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_b", "block_k", "block_nw", "interpret")
+    jax.jit,
+    static_argnames=("out_words", "block_b", "block_k", "block_nw",
+                     "interpret"),
 )
-def _batched_walk_kernel_call(mask_bits, *planes, block_b, block_k, block_nw,
-                              interpret):
-    # One common padded dim: every hop's rows AND packed cols fit inside it.
-    words = max([mask_bits.shape[1]]
-                + [max(-(-p.shape[0] // 32), p.shape[1]) for p in planes])
-    nw = _round_up(words, _lane(interpret))
+def _walk_stack(mask_bits, stacked, *, out_words, block_b, block_k, block_nw,
+                interpret):
+    nw = stacked.shape[2]
     b = mask_bits.shape[0]
     bb = min(block_b, _round_up(b, 8))
     mask_p = _pad2(_as_i32(mask_bits), _round_up(b, bb), nw)
-    stacked = jnp.stack(
-        [bit_major_rows(_pad2(_as_i32(p), 32 * nw, nw)) for p in planes])
     out_p, counts_p = batched_walk_pallas(
         mask_p, stacked, block_b=bb, block_k=math.gcd(block_k, nw),
         block_nw=math.gcd(block_nw, nw), interpret=interpret,
     )
-    out = out_p[:b, : planes[-1].shape[1]]
+    out = out_p[:b, :out_words]
     return jax.lax.bitcast_convert_type(out, jnp.uint32), counts_p[:, :b, 0]
+
+
+def prepare_walk_planes(
+    planes,
+    *,
+    interpret: bool | None = None,
+    use_pallas: bool | None = None,
+) -> WalkPlanes:
+    """The K hop planes of a chain as the fused walk's device operand.
+
+    ``planes[j]`` is hop j's packed (n_j, ⌈n_{j+1}/32⌉) relation bitplane.
+    On the Pallas path they are zero-padded to the one common square dim,
+    reordered bit-major and stacked (one jit'd program); on the oracle path
+    they are put on the device as they are.  The result depends on the
+    planes alone, so a caller that walks one chain many times keeps it and
+    ships only each call's mask (:func:`batched_walk_prepared`).
+    """
+    planes = [jnp.asarray(p, dtype=jnp.uint32) for p in planes]
+    if not planes:
+        raise ValueError("batched_walk needs at least one hop")
+    shapes = tuple(tuple(p.shape) for p in planes)
+    _check_walk_chain(-(-shapes[0][0] // 32), shapes)
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    if interpret is None:
+        interpret = not on_tpu()
+    operand = (_prepare_walk_stack(*planes, interpret=interpret)
+               if use_pallas else tuple(planes))
+    return WalkPlanes(operand, shapes, use_pallas, interpret)
+
+
+def batched_walk_prepared(
+    mask_bits,
+    prepared: WalkPlanes,
+    *,
+    block_b: int = 128,
+    block_k: int = 512,
+    block_nw: int = 128,
+):
+    """:func:`batched_walk` over a prepared plane operand: only the mask is
+    padded, and the answer is the same ``(out_bits, counts)``."""
+    mask_bits = jnp.asarray(mask_bits, dtype=jnp.uint32)
+    _check_walk_chain(mask_bits.shape[1], prepared.shapes)
+    _note_launch("batched_walk", prepared.use_pallas and not prepared.interpret)
+    if not prepared.use_pallas:
+        return _batched_walk_oracle(mask_bits, *prepared.operand,
+                                    n_hops=len(prepared.shapes))
+    return _walk_stack(mask_bits, prepared.operand,
+                       out_words=prepared.shapes[-1][1], block_b=block_b,
+                       block_k=block_k, block_nw=block_nw,
+                       interpret=prepared.interpret)
 
 
 def batched_walk(
@@ -310,23 +394,14 @@ def batched_walk(
     fused Pallas kernel on TPU, the jit'd jnp oracle (still one dispatch)
     elsewhere.  For the Pallas path every hop dim is zero-padded to one
     common square dim (inert under (OR, AND)) and the planes stack into a
-    single streamed operand; see :mod:`repro.kernels.batched_walk`.
+    single streamed operand; see :mod:`repro.kernels.batched_walk`.  It is
+    :func:`prepare_walk_planes` then :func:`batched_walk_prepared`: a
+    caller that walks one chain many times keeps the prepared operand.
     """
-    mask_bits = jnp.asarray(mask_bits, dtype=jnp.uint32)
-    planes = [jnp.asarray(p, dtype=jnp.uint32) for p in planes]
-    if not planes:
-        raise ValueError("batched_walk needs at least one hop")
-    _check_walk_chain(mask_bits, planes)
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if interpret is None:
-        interpret = not on_tpu()
-    _note_launch("batched_walk", use_pallas and not interpret)
-    if not use_pallas:
-        return _batched_walk_oracle(mask_bits, *planes, n_hops=len(planes))
-    return _batched_walk_kernel_call(mask_bits, *planes, block_b=block_b,
-                                     block_k=block_k, block_nw=block_nw,
-                                     interpret=interpret)
+    return batched_walk_prepared(
+        mask_bits,
+        prepare_walk_planes(planes, interpret=interpret, use_pallas=use_pallas),
+        block_b=block_b, block_k=block_k, block_nw=block_nw)
 
 
 def batched_walk_unfused(

@@ -151,7 +151,14 @@ class QuerySession:
             "walk_wait_ns": 0,
             "host_walk_ns": 0,
             "hopcache_probe_ns": 0,
+            # the fused walk's plane operands kept on the device (one per
+            # chain and direction): lookups, LRU evictions, resident bytes
+            "walk_plane_hits": 0,
+            "walk_plane_misses": 0,
+            "walk_plane_evictions": 0,
+            "walk_plane_bytes": 0,
         }
+        self.walk_planes = Q.WalkPlaneStore(self.counters)
 
     # -- planning --------------------------------------------------------------
     def _plan_pairs(self, plan: QueryPlan) -> Optional[List[Tuple[str, str]]]:
@@ -369,6 +376,7 @@ class QuerySession:
             fused = Q.fused_walk_record_masks_batch(
                 self.index, plan.source, plan.target, plan.rows,
                 plan.direction, counters=self.counters,
+                planes=self.walk_planes,
             )
             if fused is not None:  # non-linear chains fall through to the walk
                 self.counters["fused_walk"] += 1

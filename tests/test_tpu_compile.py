@@ -79,19 +79,41 @@ def test_bitmatmul_compiles_for_v5e(one_chip, no_compile_cache, m, k, n):
     _check(compiled)
 
 
-@pytest.mark.parametrize("b,hops,n", [
+# the fused walk in its two steps: the plane operand prepared once, then
+# walked with only the mask padded
+WALK_WIDTHS = [
     (64, 4, 1000),           # German
-    (64, 7, 7214),           # Compas
-    (64, 8, 16384),          # deep chain at the 256 MiB plane cap
-])
+    (64, 7, 7214),           # Compas: nw 256
+    (64, 8, 16384),          # deep chain at the 256 MiB plane cap: nw 512
+]
+
+
+def _nw(n: int) -> int:
+    return -(-_words(n) // 128) * 128
+
+
+@pytest.mark.parametrize("b,hops,n", WALK_WIDTHS)
+def test_walk_plane_prepare_compiles_for_v5e(one_chip, no_compile_cache, b,
+                                             hops, n):
+    plane = jax.ShapeDtypeStruct((n, _words(n)), jnp.uint32,
+                                 sharding=one_chip)
+    compiled = K._prepare_walk_stack.lower(
+        *([plane] * hops), interpret=False).compile()
+    assert compiled.out_info.shape == (hops, 32 * _nw(n), _nw(n))
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("b,hops,n", WALK_WIDTHS)
 def test_batched_walk_compiles_for_v5e(one_chip, no_compile_cache, b, hops,
                                        n):
     mask = jax.ShapeDtypeStruct((b, _words(n)), jnp.uint32, sharding=one_chip)
-    plane = jax.ShapeDtypeStruct((n, _words(n)), jnp.uint32,
-                                 sharding=one_chip)
-    compiled = K._batched_walk_kernel_call.lower(
-        mask, *([plane] * hops), block_b=128, block_k=512, block_nw=128,
-        interpret=False,
+    stacked = jax.ShapeDtypeStruct((hops, 32 * _nw(n), _nw(n)), jnp.int32,
+                                   sharding=one_chip)
+    compiled = K._walk_stack.lower(
+        mask, stacked, out_words=_words(n), block_b=128, block_k=512,
+        block_nw=128, interpret=False,
     ).compile()
     _check(compiled)
 
