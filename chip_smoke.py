@@ -17,8 +17,10 @@ session (the per-op walker: no hop-cache, no fused kernel):
     bitmatmul on TPU) for every use case whose source-to-sink plane fits
     the hop-cache budget;
 (b) the TPC-DI SF3 join (362,342 x 390,978 rows): batched trace-to-source
-    (B=64) both ways, reporting which hop-cache entries hold what and
-    whether the fused walk's planes would fit;
+    (B=64) both ways; its planes are far over the fused walk's cap, so the
+    gather walk answers each plan from the join's row map on the device
+    (``gather_walk``, and ``fused_walk_oversize`` for a refusal); it also
+    reports which hop-cache entries hold what;
 (c) the fused K-hop walk at its plane cap: a deep chain with n=16384 and
     K=8 (256 MiB of planes), B=64 both ways; the fused kernel must answer,
     and the per-hop kernels it replaces must give the same frontier;
@@ -206,6 +208,14 @@ def phase_tpcdi(scale: int = 3, seed: int = 0, count: int = N_PROBES) -> dict:
         probe_sets(rng, n_sink, count)).backward().to("tpcdi_right").plan()
     for kind, plan in plans.items():
         check(f"tpcdi {kind}", sess.run(plan), ref.run(plan))
+    routed = {k: sess.counters[k] for k in ("gather_walk",
+                                            "fused_walk_oversize")}
+    # at every Table XI scale factor the join's planes are far over the
+    # fused walk's cap, so on the chip its row map must answer every plan
+    if K.on_tpu() and routed != {"gather_walk": len(plans),
+                                 "fused_walk_oversize": 0}:
+        raise RuntimeError(
+            f"the gather walk did not answer every plan: {sess.counters}")
     # the session walks single-hop joins; probing the hop-cache directly
     # shows which backend its density rule picks for each side of the join
     check("tpcdi hop-cache fwd", sess.composed.q1_forward(
@@ -221,6 +231,7 @@ def phase_tpcdi(scale: int = 3, seed: int = 0, count: int = N_PROBES) -> dict:
         "join_rows": n_sink,
         "fused_plane_bytes": plane_bytes(index, "tpcdi_right",
                                          sink.dataset_id),
+        **routed,
         "session": session_counters(sess),
         "hopcache": hopcache_counters(sess.composed),
     }
@@ -370,7 +381,7 @@ def main(argv=None) -> int:
             _, lc = run_phase(label, fn, *a)
             for k, v in lc.items():
                 pallas[k] = pallas.get(k, 0) + v
-        missing = [k for k in ("bitmatmul", "batched_walk")
+        missing = [k for k in ("bitmatmul", "batched_walk", "gather_walk")
                    if not pallas.get(k)]
         if missing:
             print(f"chip_smoke: no compiled Pallas launch of {missing}",

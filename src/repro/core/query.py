@@ -44,7 +44,7 @@ from __future__ import annotations
 import dataclasses
 import warnings
 from collections import OrderedDict
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple, Union
 
 import jax
 import numpy as np
@@ -132,6 +132,21 @@ def _as_mask_batch(rows_batch, n: int) -> np.ndarray:
         out[np.arange(rows_batch.shape[0])[:, None], rows_batch.astype(np.int64)] = True
         return out
     return np.stack([_as_mask(r, n) for r in rows_batch], axis=0)
+
+
+def mask_rows(stack: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """The probe and the row of each set entry of a ``(B, n)`` bool stack,
+    in row-major order: ``np.divmod(np.flatnonzero(stack), n)``.  A sparse
+    stack is scanned once, as 8-byte words, and only the words that hold a
+    set entry are looked at byte by byte."""
+    flat = np.ascontiguousarray(stack).reshape(-1)
+    if flat.dtype == bool and flat.size % 8 == 0:
+        words = np.flatnonzero(flat.view(np.uint64) != 0)
+        word, byte = np.nonzero(flat.reshape(-1, 8)[words])
+        ids = words[word] * 8 + byte
+    else:
+        ids = np.flatnonzero(flat)
+    return np.divmod(ids, stack.shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -254,12 +269,14 @@ def plane_store_budget(max_plane_bytes: int) -> int:
 
 
 class WalkPlaneStore:
-    """Least-recently-used fused-walk plane operands, resident on the device.
+    """Least-recently-used fused-walk operands, resident on the device.
 
-    Keyed by ``(direction, ((op_id, slot), ...))``: the index is
-    append-only and an op's tensor never changes (spilling swaps it for a
-    fault proxy of the same content), so a chain's prepared operand
-    (:func:`repro.kernels.ops.prepare_walk_planes`) stays exact for the
+    Plane stacks (:func:`repro.kernels.ops.prepare_walk_planes`) are keyed
+    by ``(direction, ((op_id, slot), ...))``, row maps
+    (:func:`repro.kernels.ops.prepare_gather_walk`, both directions in one)
+    by ``("gather", ((op_id, slot), ...))``.  The index is append-only and
+    an op's tensor never changes (spilling swaps it for a fault proxy of
+    the same content), so a chain's prepared operand stays exact for the
     index's life.  A store holds the operands of one ``use_pallas``
     routing.  It keeps its tally in the owner's ``counters``:
     ``walk_plane_hits``, ``walk_plane_misses``, ``walk_plane_evictions``
@@ -302,7 +319,8 @@ def fused_walk_record_masks_batch(
     max_plane_bytes: int = 256 << 20,
     counters: Optional[Dict[str, int]] = None,
     planes: Optional[WalkPlaneStore] = None,
-) -> Optional[np.ndarray]:
+    per_probe: bool = False,
+) -> Optional[Union[np.ndarray, List[np.ndarray]]]:
     """``(B, n_dst)`` bool answered in ONE kernel launch, or None to fall back.
 
     The fused :func:`repro.kernels.ops.batched_walk` replaces the per-op
@@ -311,11 +329,14 @@ def fused_walk_record_masks_batch(
     on to the downstream end must lie on the
     :func:`~repro.core.compose.path_tensors` chain.  Diamonds, self-joins
     and side entrances fail that audit and return None — the caller falls
-    back to the full per-op walker (which sums over all paths).  None is
-    also returned when the square-padded plane stack the fused kernel
-    streams would exceed ``max_plane_bytes``; that refusal is counted in
-    ``counters["fused_walk_oversize"]`` when a counter dict is given, so a
-    walk routed to the host for size is visible.
+    back to the full per-op walker (which sums over all paths).  When the
+    square-padded plane stack the fused kernel streams would exceed
+    ``max_plane_bytes``, a chain whose every hop is structured walks its
+    composed row map instead (:func:`_gather_walk`, counted in
+    ``counters["gather_walk"]``); any other chain returns None, and that
+    refusal is counted in ``counters["fused_walk_oversize"]`` when a
+    counter dict is given, so a walk routed to the host for size is
+    visible.
 
     ``direction="bwd"`` probes ``src`` (the downstream end) and answers at
     ``dst`` through the transposed planes of the reversed chain; forward
@@ -330,21 +351,26 @@ def fused_walk_record_masks_batch(
     (:class:`WalkPlaneStore`, budget :func:`plane_store_budget`).
 
     The walk runs in the span ``walk.fused``, with the host-to-device copy
-    of the packed mask and, on a miss, the planes in ``walk.put`` and the
-    wait for the answer in ``walk.wait`` (:mod:`repro.core.spans`); with a
-    counter dict their nanoseconds accrue to ``fused_walk_ns``,
-    ``walk_put_ns`` and ``walk_wait_ns``, and the bytes put to
-    ``walk_put_bytes``.
+    of the packed mask (the row ids on the gather route) and, on a miss,
+    the operand in ``walk.put`` and the wait for the answer in
+    ``walk.wait`` (:mod:`repro.core.spans`); the gather route runs in
+    ``walk.gather`` around both.  With a counter dict their nanoseconds
+    accrue to ``fused_walk_ns``, ``gather_walk_ns``, ``walk_put_ns`` and
+    ``walk_wait_ns``, and the bytes put to ``walk_put_bytes``.
+
+    With ``per_probe`` the gather route answers a list of each probe's
+    rows instead (``np.flatnonzero`` of each row of the stack), and makes
+    no ``(B, n_dst)`` stack; the dense route answers the stack either way.
     """
     with span("walk.fused", counters, "fused_walk_ns"):
         return _fused_walk(index, src, dst, rows_batch, direction, use_pallas,
-                           max_plane_bytes, counters, planes)
+                           max_plane_bytes, counters, planes, per_probe)
 
 
 def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
                 direction: str, use_pallas: Optional[bool],
                 max_plane_bytes: int, counters: Optional[Dict[str, int]],
-                planes: Optional[WalkPlaneStore]) -> Optional[np.ndarray]:
+                planes: Optional[WalkPlaneStore], per_probe: bool):
     from repro.core.compose import path_tensors
 
     up, down = (src, dst) if direction == "fwd" else (dst, src)
@@ -379,14 +405,22 @@ def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
         return None
 
     # the fused kernel square-pads every hop to one common dim — cap the
-    # streamed plane stack before materializing any bitplane
+    # streamed plane stack before materializing any bitplane; a chain of
+    # structured hops over the cap walks its composed row map instead
     n_max = max(
         max(op.tensor.n_in[slot], op.tensor.n_out) for op, slot in chain
     )
     if len(chain) * n_max * n_max // 8 > max_plane_bytes:
-        if counters is not None:
+        out = None
+        if all(op.tensor.slot_structure(slot) is not None
+               for op, slot in chain):
+            with span("walk.gather", counters, "gather_walk_ns"):
+                out = _gather_walk(index, chain, stack, dst, direction,
+                                   use_pallas, max_plane_bytes, counters,
+                                   planes, per_probe)
+        if out is None and counters is not None:
             counters["fused_walk_oversize"] += 1
-        return None
+        return out
 
     from repro.kernels import ops as K
 
@@ -415,6 +449,68 @@ def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
     with span("walk.wait", counters, "walk_wait_ns"):
         out_host = np.asarray(out_bits)
     return unpack_bitplane(out_host, index.datasets[dst].n_rows)
+
+
+def _gather_walk(index: ProvenanceIndex, chain, stack: np.ndarray, dst: str,
+                 direction: str, use_pallas: Optional[bool],
+                 max_plane_bytes: int, counters: Optional[Dict[str, int]],
+                 planes: Optional[WalkPlaneStore], per_probe: bool):
+    """The fused walk of a chain of structured hops: its composed row map
+    (:func:`repro.core.compose.chain_gather`), resident in ``planes`` under
+    one key for both directions, probed by the probes' row ids
+    (:func:`repro.kernels.ops.gather_walk`).  The mask stack crosses to the
+    device as row ids and the answer comes back as rows, so nothing is
+    packed on the host, and with ``per_probe`` the answer is each probe's
+    rows, with no ``(B, n_dst)`` stack made.  None when the answer table
+    would exceed :data:`repro.kernels.ops.GATHER_TABLE_BYTES`.  With a
+    counter dict, a plan adds its probe rows to ``gather_walk_rows``, the
+    answer rows the table held to ``gather_walk_hits`` and the map's links
+    to ``gather_walk_links``."""
+    from repro.core.compose import chain_gather
+    from repro.kernels import ops as K
+
+    key = ("gather", tuple((op.op_id, slot) for op, slot in chain))
+    gmap = planes.get(key) if planes is not None else None
+    probe, rows = mask_rows(stack)
+    # a few padded sizes, so that each compiles once
+    q = max(K.GATHER_BLOCK_Q, 1 << (len(rows) - 1).bit_length())
+    queries = np.full(q, -1, dtype=np.int32)
+    queries[:len(rows)] = rows
+    put_bytes = queries.nbytes
+    with span("walk.put", counters, "walk_put_ns"):
+        queries_dev = jax.device_put(queries)
+        if gmap is None:
+            first, slot = chain[0]
+            gmap = K.prepare_gather_walk(
+                chain_gather(chain), first.tensor.n_in[slot],
+                use_pallas=use_pallas)
+            put_bytes += gmap.nbytes
+            if planes is not None:
+                planes.keep(key, gmap, plane_store_budget(max_plane_bytes))
+    if counters is not None:
+        counters["walk_put_bytes"] += put_bytes
+    deg = (gmap.fwd if direction == "fwd" else gmap.bwd)[2]
+    if q * deg * 4 > K.GATHER_TABLE_BYTES:
+        return None
+    table_dev = K.gather_walk(queries_dev, gmap, direction)
+    with span("walk.wait", counters, "walk_wait_ns"):
+        table = np.asarray(table_dev)[:len(rows)]
+    hit, col = np.nonzero(table >= 0)
+    if counters is not None:
+        counters["gather_walk"] += 1
+        counters["gather_walk_rows"] += len(rows)
+        counters["gather_walk_hits"] += len(hit)
+        counters["gather_walk_links"] += gmap.links
+    n_dst = index.datasets[dst].n_rows
+    at, found = np.divmod(np.unique(probe[hit] * n_dst + table[hit, col]),
+                          n_dst)
+    if per_probe:
+        # cut before each probe 0..B: the pieces between are probes 0..B-1
+        cuts = np.searchsorted(at, np.arange(len(stack) + 1))
+        return np.split(found, cuts)[1:-1]
+    out = np.zeros((len(stack), n_dst), dtype=bool)
+    out[at, found] = True
+    return out
 
 
 # ---------------------------------------------------------------------------

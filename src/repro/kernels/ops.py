@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.bitmatmul import bit_major_rows, bitmatmul_pallas
-from repro.kernels.lineage_gather import lineage_gather_pallas
+from repro.kernels.lineage_gather import (LANES, VALUE_TAIL,
+                                          lineage_gather_pallas, vmem_bytes)
 from repro.kernels.bitset_rank import bitset_rank_pallas
 from repro.kernels.batched_walk import batched_walk_pallas
 from repro.kernels import ref
@@ -30,6 +31,9 @@ __all__ = [
     "batched_walk_prepared",
     "prepare_walk_planes",
     "WalkPlanes",
+    "gather_walk",
+    "prepare_gather_walk",
+    "GatherMap",
     "on_tpu",
     "launch_counts",
     "pallas_launch_counts",
@@ -177,45 +181,86 @@ def bitplane_probe(mask_bits, plane_bits, *, use_pallas: bool | None = True, **k
     return bitmatmul(mask_bits, plane_bits, use_pallas=use_pallas, **kw)
 
 
+# VMEM a lineage_gather launch may ask for; a larger col_idx is probed by
+# the jnp oracle, still on the device
+GATHER_VMEM_BYTES = 64 << 20
+GATHER_BLOCK_Q = 128
+GATHER_TILE = 8     # 128-lane chunks of the table per grid step
+
+
+def _gather_fits(n_col: int) -> bool:
+    """Whether the Pallas probe of a ``col_idx`` of ``n_col`` entries fits
+    :data:`GATHER_VMEM_BYTES` (whatever the table's width)."""
+    n = _round_up(n_col, LANES) + VALUE_TAIL
+    return vmem_bytes(n, GATHER_BLOCK_Q, GATHER_TILE) <= GATHER_VMEM_BYTES
+
+
+def _gather_col_idx(col_idx: np.ndarray) -> np.ndarray:
+    """``(1, L)`` int32: ``col_idx`` padded with ``-1`` to whole lanes plus
+    the kernel's tail."""
+    out = np.full(_round_up(len(col_idx), LANES) + VALUE_TAIL, -1, np.int32)
+    out[:len(col_idx)] = col_idx
+    return out[None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("max_deg", "block_q",
+                                             "interpret", "name"))
+def _lineage_gather_call(queries, row_ptr, col_p, *, max_deg,
+                         block_q=GATHER_BLOCK_Q, interpret, name):
+    """The kernel on ``(Q,)`` queries (``-1`` = none): offsets looked up
+    here, the table laid out ``(Q, max_deg)``."""
+    q = queries.shape[0]
+    qp = _pad_to(queries, 0, block_q, value=-1)
+    valid = qp >= 0
+    safe = jnp.where(valid, qp, 0)
+    starts = jnp.where(valid, row_ptr[safe], 0)
+    lens = jnp.where(valid, row_ptr[safe + 1] - starts, 0)
+    blocks = (qp.shape[0] // block_q, 1, block_q)
+    md = _round_up(max_deg, LANES)
+    # the most chunks per grid step that tile the table exactly
+    tile = max(t for t in range(1, GATHER_TILE + 1) if md // LANES % t == 0)
+    out = lineage_gather_pallas(
+        starts.reshape(blocks), lens.reshape(blocks), col_p, max_deg=md,
+        block_q=block_q, tile=tile, interpret=interpret, name=name)
+    return out.transpose(1, 0, 2).reshape(qp.shape[0], -1)[:q, :max_deg]
+
+
+@functools.partial(jax.jit, static_argnames=("max_deg",))
+def _lineage_gather_oracle(queries, row_ptr, col_p, *, max_deg):
+    return ref.lineage_gather_ref(queries, row_ptr, col_p[0], max_deg=max_deg)
+
+
 def lineage_gather(
     row_ptr,
     col_idx,
     queries,
     *,
     max_deg: int,
-    block_q: int = 128,
+    block_q: int = GATHER_BLOCK_Q,
     interpret: bool | None = None,
     use_pallas: bool | None = True,
 ):
-    """Batched CSR probe -> (Q, max_deg) padded neighbor table.
+    """Batched CSR probe -> (Q, max_deg) padded neighbor table (a query of
+    ``-1`` probes nothing).
 
     ``use_pallas=None`` applies the same kernel-launch guard as
-    :func:`bitmatmul`: Pallas on TPU, the jnp oracle elsewhere.
+    :func:`bitmatmul`: Pallas on TPU, the jnp oracle elsewhere and for a
+    ``col_idx`` too large for :data:`GATHER_VMEM_BYTES`.
     """
-    row_ptr = jnp.asarray(row_ptr, dtype=jnp.int32)
-    col_idx = jnp.asarray(col_idx, dtype=jnp.int32)
-    queries = jnp.asarray(queries, dtype=jnp.int32)
+    col_idx = np.asarray(col_idx, dtype=np.int32).reshape(-1)
     if use_pallas is None:
-        use_pallas = on_tpu()
+        use_pallas = on_tpu() and _gather_fits(len(col_idx))
     if interpret is None:
         interpret = not on_tpu()
     _note_launch("lineage_gather", use_pallas and not interpret)
-    q = queries.shape[0]
+    args = (jnp.asarray(queries, dtype=jnp.int32),
+            jnp.asarray(row_ptr, dtype=jnp.int32),
+            jnp.asarray(_gather_col_idx(col_idx)))
     md = max(int(max_deg), 1)
-    # Sentinel-pad col_idx so the dynamic slice never reads OOB.
-    col_p = jnp.concatenate([col_idx, jnp.full((md,), -1, jnp.int32)])
     if not use_pallas:
-        return ref.lineage_gather_ref(queries, row_ptr, col_p, max_deg=md)
-    md_pad = _round_up(md, 128)
-    # sentinels cover the last query's aligned window: see lineage_gather.py
-    tail = _round_up(col_idx.shape[0], 128) - col_idx.shape[0] + md_pad + 128
-    col_p = jnp.concatenate([col_idx, jnp.full((tail,), -1, jnp.int32)])
-    q_p = _pad_to(queries, 0, block_q)
-    out = lineage_gather_pallas(
-        q_p, row_ptr, col_p[None, :], max_deg=md_pad, block_q=block_q,
-        interpret=interpret
-    )
-    return out[:q, :md]
+        return _lineage_gather_oracle(*args, max_deg=md)
+    return _lineage_gather_call(*args, max_deg=md, block_q=block_q,
+                                interpret=interpret, name=None)
 
 
 def bitset_rank(
@@ -451,3 +496,90 @@ def batched_walk_unfused(
         lineage_gather(row_ptr, col_idx, np.arange(b, dtype=np.int32),
                        max_deg=md, use_pallas=use_pallas, interpret=interpret)
     return jnp.asarray(cur), jnp.asarray(np.stack(all_counts, axis=0))
+
+
+# ---------------------------------------------------------------------------
+# Gather walk: record probes through a composed row map
+# ---------------------------------------------------------------------------
+# device bytes one gather walk's answer table may take; a plan that would
+# need more is answered on the host
+GATHER_TABLE_BYTES = 256 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class GatherMap:
+    """A composed row map's device operand, made once by
+    :func:`prepare_gather_walk` and walked by :func:`gather_walk` in both
+    directions.
+
+    ``fwd`` and ``bwd`` each hold ``(offsets, col_idx, deg)``: a CSR over
+    the rows a probe of that direction names, as :func:`lineage_gather`
+    probes one, and the answer table's width (the largest segment, padded
+    to whole lanes).  ``links`` counts the map's links (its rows other
+    than ``-1``)."""
+
+    fwd: tuple
+    bwd: tuple
+    links: int
+    use_pallas: bool
+    interpret: bool
+
+    @property
+    def nbytes(self) -> int:
+        """Device bytes the operand holds."""
+        return sum(x.nbytes for d in (self.fwd, self.bwd) for x in d[:2])
+
+
+def prepare_gather_walk(
+    g,
+    n_src: int,
+    *,
+    interpret: bool | None = None,
+    use_pallas: bool | None = None,
+) -> GatherMap:
+    """A destination→source row map ``g`` (int32 ``(n_dst,)``, ``-1`` = no
+    link) as the gather walk's device operand.
+
+    ``bwd`` probes destination rows: row ``j``'s segment is ``g[j]`` (empty
+    where ``-1``).  ``fwd`` probes the ``n_src`` source rows: row ``i``'s
+    segment is every ``j`` with ``g[j] = i``, ascending (the inverse map).
+    ``use_pallas=None`` is :func:`lineage_gather`'s kernel-launch guard."""
+    g = np.asarray(g, dtype=np.int64).reshape(-1)
+    dst = np.flatnonzero(g >= 0)
+    src = g[dst]
+    counts = np.bincount(src, minlength=n_src)
+    fwd_ptr = np.zeros(n_src + 1, np.int64)
+    np.cumsum(counts, out=fwd_ptr[1:])
+    bwd_ptr = np.zeros(len(g) + 1, np.int64)
+    np.cumsum(g >= 0, out=bwd_ptr[1:])
+    fwd_deg = _round_up(max(int(counts.max(initial=0)), 1), LANES)
+    if use_pallas is None:
+        use_pallas = on_tpu() and _gather_fits(len(src))
+    if interpret is None:
+        interpret = not on_tpu()
+
+    def csr(ptr, col_idx, deg):
+        return (jax.device_put(ptr.astype(np.int32)),
+                jax.device_put(_gather_col_idx(col_idx)), deg)
+
+    return GatherMap(
+        fwd=csr(fwd_ptr, dst[np.argsort(src, kind="stable")], fwd_deg),
+        bwd=csr(bwd_ptr, src, LANES), links=len(src),
+        use_pallas=bool(use_pallas), interpret=bool(interpret))
+
+
+def gather_walk(queries, gmap: GatherMap, direction: str):
+    """Record probe rows through a prepared row map in ONE kernel launch
+    (the :func:`lineage_gather` kernel, named ``gather_walk``).
+
+    ``queries`` (Q,) int32 names probe rows of the ``direction``'s probed
+    side (``-1`` = none).  Returns the ``(Q, deg)`` int32 table whose row q
+    lists the rows that ``queries[q]`` reaches, then ``-1``; the caller
+    ORs each row into its probe's answer."""
+    ptr, col_p, deg = gmap.fwd if direction == "fwd" else gmap.bwd
+    queries = jnp.asarray(queries, dtype=jnp.int32)
+    _note_launch("gather_walk", gmap.use_pallas and not gmap.interpret)
+    if not gmap.use_pallas:
+        return _lineage_gather_oracle(queries, ptr, col_p, max_deg=deg)
+    return _lineage_gather_call(queries, ptr, col_p, max_deg=deg,
+                                interpret=gmap.interpret, name="gather_walk")

@@ -51,13 +51,16 @@ def bitmatmul_ref(a_bits: jax.Array, b_bits: jax.Array) -> jax.Array:
 def lineage_gather_ref(
     queries: jax.Array, row_ptr: jax.Array, col_idx: jax.Array, *, max_deg: int
 ) -> jax.Array:
-    """Padded (Q, max_deg) neighbor table oracle (col_idx sentinel-padded)."""
-    starts = row_ptr[queries]
-    ends = row_ptr[queries + 1]
+    """Padded (Q, max_deg) neighbor table oracle: row q holds the segment
+    ``col_idx[row_ptr[r] : row_ptr[r + 1]]`` of ``r = queries[q]``, then
+    ``-1``; a query of ``-1`` has an empty segment."""
+    valid = queries >= 0
+    safe = jnp.where(valid, queries, 0)
+    starts = row_ptr[safe]
+    lens = jnp.where(valid, row_ptr[safe + 1] - starts, 0)
     lane = jnp.arange(max_deg, dtype=jnp.int32)[None, :]
-    gather_idx = starts[:, None] + lane
-    seg = col_idx[gather_idx]
-    return jnp.where(lane < (ends - starts)[:, None], seg, jnp.int32(-1))
+    seg = col_idx[jnp.minimum(starts[:, None] + lane, col_idx.shape[0] - 1)]
+    return jnp.where(lane < lens[:, None], seg, jnp.int32(-1))
 
 
 def batched_walk_ref(mask_bits: jax.Array, planes) -> tuple:

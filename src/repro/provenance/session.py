@@ -136,23 +136,34 @@ class QuerySession:
             "walk": 0,
             "hopcache": 0,
             "fused_walk": 0,
-            # linear chains the fused kernel declined for size (host walk)
+            # fused walks answered from a composed row map on the device
+            # (chains of structured hops too large for dense planes)
+            "gather_walk": 0,
+            # and, over those walks, the probe rows looked up, the answer
+            # rows found and the walked maps' links
+            "gather_walk_rows": 0,
+            "gather_walk_hits": 0,
+            "gather_walk_links": 0,
+            # linear chains the fused walk declined for size (host walk)
             "fused_walk_oversize": 0,
             "meta": 0,
             "fused_groups": 0,
             "fused_plans": 0,
             # nanoseconds in each span of the record path (bytes for
             # walk_put_bytes): session.execute, then inside it walk.fused
-            # (with walk.put and walk.wait), walk.host or hopcache.probe
+            # (with walk.gather for a row-map walk, and walk.put and
+            # walk.wait), walk.host or hopcache.probe
             "execute_ns": 0,
             "fused_walk_ns": 0,
+            "gather_walk_ns": 0,
             "walk_put_ns": 0,
             "walk_put_bytes": 0,
             "walk_wait_ns": 0,
             "host_walk_ns": 0,
             "hopcache_probe_ns": 0,
-            # the fused walk's plane operands kept on the device (one per
-            # chain and direction): lookups, LRU evictions, resident bytes
+            # the fused walk's operands kept on the device (planes: one
+            # per chain and direction; row maps: one per chain): lookups,
+            # LRU evictions, resident bytes
             "walk_plane_hits": 0,
             "walk_plane_misses": 0,
             "walk_plane_evictions": 0,
@@ -192,7 +203,7 @@ class QuerySession:
         uncached = [p for p in pairs if not self.composed.contains(*p)]
         if not uncached:
             return None  # every leg already composed: contains-path decides
-        probe_rows = (float(plan.rows.sum()) / max(plan.n_probes, 1)
+        probe_rows = (np.count_nonzero(plan.rows) / max(plan.n_probes, 1)
                       if plan.rows is not None else 1.0)
         legs = [
             self.costmodel.choose(
@@ -362,11 +373,14 @@ class QuerySession:
             return self._exec_co_dependency(plan, strategy)
         raise ValueError(f"unexpected plan kind {plan.kind!r}")
 
-    def _record_masks(self, plan: QueryPlan, strategy: str) -> np.ndarray:
+    def _record_masks(self, plan: QueryPlan, strategy: str,
+                      per_probe: bool = False):
         """The one plain-record executor: (B, n_target) bool per strategy.
         Both :meth:`run` (via ``_exec_record``) and :meth:`run_masks` (the
         federation's segment hook) answer through this, so routing and
-        fallback shapes cannot diverge between the two surfaces."""
+        fallback shapes cannot diverge between the two surfaces.  With
+        ``per_probe`` a fused walk may answer a list of each probe's rows
+        instead (the gather route, which then makes no dense stack)."""
         if strategy == "hopcache":
             probe = (self.composed.probe_forward if plan.direction == "fwd"
                      else self.composed.probe_backward)
@@ -376,7 +390,7 @@ class QuerySession:
             fused = Q.fused_walk_record_masks_batch(
                 self.index, plan.source, plan.target, plan.rows,
                 plan.direction, counters=self.counters,
-                planes=self.walk_planes,
+                planes=self.walk_planes, per_probe=per_probe,
             )
             if fused is not None:  # non-linear chains fall through to the walk
                 self.counters["fused_walk"] += 1
@@ -396,7 +410,8 @@ class QuerySession:
 
     def _exec_record(self, plan: QueryPlan, strategy: str) -> List:
         if not plan.how:
-            return _flatnonzeros(self._record_masks(plan, strategy))
+            out = self._record_masks(plan, strategy, per_probe=True)
+            return out if isinstance(out, list) else _flatnonzeros(out)
         # how-traces only live on the walk (see _strategy)
         walker = (
             Q.forward_record_masks_batch

@@ -4,9 +4,9 @@ The TPU compiler is installed even where no chip is attached: it compiles
 for a described ``v5e:2x2`` topology and refuses what the chip would refuse
 (block tiling, unsupported primitives, scoped VMEM, device memory), which
 interpret-mode parity tests never see.  Shapes are the widths the chip
-smoke (``chip_smoke.py``) runs: the Table VIII use cases, and the fused
-walk at its 256 MiB plane cap.  Nothing runs, so these say nothing about
-results or times.
+smoke (``chip_smoke.py``) runs: the Table VIII use cases, the fused walk
+at its 256 MiB plane cap, and the gather walk over the TPC-DI SF3 join.
+Nothing runs, so these say nothing about results or times.
 """
 import os
 
@@ -118,22 +118,43 @@ def test_batched_walk_compiles_for_v5e(one_chip, no_compile_cache, b, hops,
     _check(compiled)
 
 
+def _compile_lineage_gather(one_chip, q, rows, nnz, max_deg, name):
+    """Compile :func:`repro.kernels.ops.lineage_gather`'s launch: ``q``
+    queries against a CSR of ``rows`` rows and ``nnz`` links."""
+    from repro.kernels.lineage_gather import VALUE_TAIL
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = K._lineage_gather_call.lower(
+        spec((q,)), spec((rows + 1,)),
+        spec((1, -(-nnz // 128) * 128 + VALUE_TAIL)),
+        max_deg=max_deg, interpret=False, name=name,
+    ).compile()
+    _check(compiled)
+
+
+@pytest.mark.parametrize("n_probe,n_links,max_deg", [
+    (390978, 362342, 128),    # TPC-DI SF3 fwd: dimension rows, inverse map
+    (362342, 362342, 128),    # TPC-DI SF3 bwd: joined rows, the map
+    # the widest table the route's cap admits at its smallest Q: one key's
+    # segment across 524,288 lanes, 512 grid steps of 8 chunks
+    (390978, 362342, K.GATHER_TABLE_BYTES // (4 * K.GATHER_BLOCK_Q)),
+])
+def test_gather_walk_compiles_for_v5e(one_chip, no_compile_cache, n_probe,
+                                      n_links, max_deg):
+    q = 64 * 4 if max_deg == 128 else K.GATHER_BLOCK_Q  # B = 64 probes of 4
+    _compile_lineage_gather(one_chip, q, n_probe, n_links, max_deg,
+                            "gather_walk")
+
+
 @pytest.mark.parametrize("rows,nnz,max_deg", [
     (64, 64 * 16384, 16384),  # unfused walk: a B=64 frontier at n=16384
     (32561, 32561, 128),      # Census width: one link per row
 ])
 def test_lineage_gather_compiles_for_v5e(one_chip, no_compile_cache, rows,
                                          nnz, max_deg):
-    from repro.kernels.lineage_gather import lineage_gather_pallas
-
-    def spec(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    compiled = lineage_gather_pallas.lower(
-        spec((128,)), spec((rows + 1,)), spec((1, nnz + max_deg + 256)),
-        max_deg=max_deg, block_q=128,
-    ).compile()
-    _check(compiled)
+    _compile_lineage_gather(one_chip, 128, rows, nnz, max_deg, None)
 
 
 @pytest.mark.parametrize("words", [
