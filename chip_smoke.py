@@ -15,15 +15,19 @@ session (the per-op walker: no hop-cache, no fused kernel):
     record plans (B=64), one cells plan and one how plan each through
     ``index.session()``; plus the forced-bitplane hop-cache (the Pallas
     bitmatmul on TPU) for every use case whose source-to-sink plane fits
-    the hop-cache budget;
+    the hop-cache budget; and Compas's record plans through the
+    benchmark's session (no hop-cache): every hop is structured, so the
+    gather walk must answer each from the chain's row map (``gather_walk``);
 (b) the TPC-DI SF3 join (362,342 x 390,978 rows): batched trace-to-source
     (B=64) both ways; its planes are far over the fused walk's cap, so the
     gather walk answers each plan from the join's row map on the device
     (``gather_walk``, and ``fused_walk_oversize`` for a refusal); it also
     reports which hop-cache entries hold what;
 (c) the fused K-hop walk at its plane cap: a deep chain with n=16384 and
-    K=8 (256 MiB of planes), B=64 both ways; the fused kernel must answer,
-    and the per-hop kernels it replaces must give the same frontier;
+    K=8 (256 MiB of planes), its hops held as explicit COO so that no row
+    map can answer, B=64 both ways; the dense fused kernel must answer
+    (``fused_walk`` and no ``gather_walk``), and the per-hop kernels it
+    replaces must give the same frontier;
 (d) the serving tier: 256 single-row lineage plans from 4 tenants through
     ``ServingTier(session, max_batch=32)`` over the Census index;
 (e) ``--four-chips`` only: ``ShardedProvenanceIndex(n_shards=4,
@@ -138,6 +142,32 @@ def plane_bytes(index, src: str, dst: str) -> int:
     return len(chain) * n_max * n_max // 8
 
 
+ROUTE_COUNTERS = ("fused_walk", "gather_walk", "gather_walk_declined",
+                  "fused_walk_oversize")
+
+
+def routes(session) -> dict:
+    return {k: session.counters[k] for k in ROUTE_COUNTERS}
+
+
+def check_gather_route(index, plans: dict, ref, fused_walk=None) -> dict:
+    """The fwd and bwd record plans through the benchmark's Compas
+    session (no hop-cache); with the fused walk on (on a TPU by default),
+    the gather walk must answer both."""
+    walk = QuerySession(index, use_hopcache=False, fused_walk=fused_walk)
+    for kind in ("fwd", "bwd"):
+        check(f"gather route {kind}", walk.run(plans[kind]),
+              ref.run(plans[kind]))
+    routed = routes(walk)
+    on = K.on_tpu() if fused_walk is None else fused_walk
+    if on and routed != {"fused_walk": 2, "gather_walk": 2,
+                         "gather_walk_declined": 0,
+                         "fused_walk_oversize": 0}:
+        raise RuntimeError(
+            f"the gather walk did not answer every record plan: {routed}")
+    return routed
+
+
 def capture_usecase(name: str, seed: int = 0):
     """Capture one Table VIII pipeline; returns (index, source, sink)."""
     make, run = USECASES[name]
@@ -174,6 +204,8 @@ def phase_usecases(names=("german", "compas", "census"), seed: int = 0,
             "session": session_counters(sess),
             "hopcache": hopcache_counters(sess.composed),
         }
+        if name == "compas":
+            line["gather_route"] = check_gather_route(index, plans, ref)
         # the forced-bitplane hop-cache composes and probes packed planes
         # (bitmatmul); only where the composed plane fits its budget
         bp = ComposedIndex(index, backend="bitplane")
@@ -241,14 +273,16 @@ def phase_fused_walk(n: int = 16384, n_ops: int = 8, seed: int = 0,
                      count: int = N_PROBES, fused_walk=None) -> dict:
     """(c): the fused K-hop walk at its plane cap, both ways."""
     index, sink = build_deep_chain(seed=seed, n=n, n_ops=n_ops)
+    for op in index.ops:  # the same relations, with no structure
+        op.tensor = op.tensor.as_coo()
     sess = QuerySession(index, use_hopcache=False, fused_walk=fused_walk)
     ref = reference(index)
     rng = np.random.default_rng(seed)
     plans = record_plans(index, "chain_src", sink, rng, count)
     for kind, plan in plans.items():
         check(f"deep chain {kind}", sess.run(plan), ref.run(plan))
-    if sess.counters["fused_walk"] != len(plans) or \
-            sess.counters["fused_walk_oversize"]:
+    if routes(sess) != {"fused_walk": len(plans), "gather_walk": 0,
+                        "gather_walk_declined": 0, "fused_walk_oversize": 0}:
         raise RuntimeError(
             f"fused walk did not answer every plan: {sess.counters}")
     # the per-hop baseline the fused kernel replaces (bitmatmul, bitset_rank

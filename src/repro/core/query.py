@@ -323,20 +323,27 @@ def fused_walk_record_masks_batch(
 ) -> Optional[Union[np.ndarray, List[np.ndarray]]]:
     """``(B, n_dst)`` bool answered in ONE kernel launch, or None to fall back.
 
-    The fused :func:`repro.kernels.ops.batched_walk` replaces the per-op
-    pass only when the ``src``→``dst`` dataflow is ONE linear chain: every
-    op-slot that both receives mass from the upstream end and can pass it
-    on to the downstream end must lie on the
-    :func:`~repro.core.compose.path_tensors` chain.  Diamonds, self-joins
-    and side entrances fail that audit and return None — the caller falls
-    back to the full per-op walker (which sums over all paths).  When the
-    square-padded plane stack the fused kernel streams would exceed
-    ``max_plane_bytes``, a chain whose every hop is structured walks its
-    composed row map instead (:func:`_gather_walk`, counted in
-    ``counters["gather_walk"]``); any other chain returns None, and that
-    refusal is counted in ``counters["fused_walk_oversize"]`` when a
-    counter dict is given, so a walk routed to the host for size is
-    visible.
+    The fused walk replaces the per-op pass only when the ``src``→``dst``
+    dataflow is ONE linear chain: every op-slot that both receives mass
+    from the upstream end and can pass it on to the downstream end must
+    lie on the :func:`~repro.core.compose.path_tensors` chain.  Diamonds,
+    self-joins and side entrances fail that audit and return None — the
+    caller falls back to the full per-op walker (which sums over all
+    paths).  A chain that passes is routed by structure first, then by
+    size:
+
+    1. a chain whose every hop is structured walks its composed row map
+       (:func:`_gather_walk`, counted in ``counters["gather_walk"]``),
+       whatever its dense planes would take;
+    2. the fused :func:`repro.kernels.ops.batched_walk` takes an
+       unstructured chain, or a structured one whose gather answer table
+       would exceed :data:`repro.kernels.ops.GATHER_TABLE_BYTES` or the
+       plane stack's own bytes (counted in
+       ``counters["gather_walk_declined"]``), when the square-padded plane
+       stack it streams fits ``max_plane_bytes``;
+    3. any other chain returns None, and that refusal is counted in
+       ``counters["fused_walk_oversize"]``, so a walk routed to the host
+       for size is visible.
 
     ``direction="bwd"`` probes ``src`` (the downstream end) and answers at
     ``dst`` through the transposed planes of the reversed chain; forward
@@ -345,7 +352,7 @@ def fused_walk_record_masks_batch(
     kernel-launch guard: the fused Pallas kernel on TPU, the one-dispatch
     jnp oracle elsewhere.
 
-    With a ``planes`` store, a chain that passed the audit and the cap
+    With a ``planes`` store, a chain that passed the audit and its route
     looks its device operand up there first: on a hit only the packed mask
     is put on the device; on a miss the planes are put, prepared and kept
     (:class:`WalkPlaneStore`, budget :func:`plane_store_budget`).
@@ -404,24 +411,46 @@ def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
     if relevant != {(op.op_id, slot) for op, slot in chain}:
         return None
 
-    # the fused kernel square-pads every hop to one common dim — cap the
-    # streamed plane stack before materializing any bitplane; a chain of
-    # structured hops over the cap walks its composed row map instead
+    from repro.kernels import ops as K
+
+    # route by structure, then by size.  The fused kernel square-pads every
+    # hop to one common dim, so its plane stack is sized before any
+    # bitplane is made; a structured chain's answer table may take neither
+    # the gather route's cap nor more than that stack
     n_max = max(
         max(op.tensor.n_in[slot], op.tensor.n_out) for op, slot in chain
     )
-    if len(chain) * n_max * n_max // 8 > max_plane_bytes:
-        out = None
-        if all(op.tensor.slot_structure(slot) is not None
-               for op, slot in chain):
-            with span("walk.gather", counters, "gather_walk_ns"):
-                out = _gather_walk(index, chain, stack, dst, direction,
-                                   use_pallas, max_plane_bytes, counters,
-                                   planes, per_probe)
-        if out is None and counters is not None:
+    plane_bytes = len(chain) * n_max * n_max // 8
+    dense = plane_bytes <= max_plane_bytes
+    # (a spilled tensor's proxy knows whether it is structured: nothing is
+    # faulted back to route a chain whose operand is resident)
+    if all(op.tensor.structured for op, _ in chain):
+        table_bytes = K.GATHER_TABLE_BYTES
+        if dense:
+            table_bytes = min(table_bytes, plane_bytes)
+        with span("walk.gather", counters, "gather_walk_ns"):
+            out = _gather_walk(index, chain, stack, dst, direction,
+                               use_pallas, max_plane_bytes, table_bytes,
+                               counters, planes, per_probe)
+        if out is not None:
+            return out
+        if dense and counters is not None:
+            counters["gather_walk_declined"] += 1
+    if not dense:
+        if counters is not None:
             counters["fused_walk_oversize"] += 1
-        return out
+        return None
+    return _dense_walk(index, chain, stack, dst, direction, use_pallas,
+                       max_plane_bytes, counters, planes)
 
+
+def _dense_walk(index: ProvenanceIndex, chain, stack: np.ndarray, dst: str,
+                direction: str, use_pallas: Optional[bool],
+                max_plane_bytes: int, counters: Optional[Dict[str, int]],
+                planes: Optional[WalkPlaneStore]) -> np.ndarray:
+    """The fused walk of a chain through its bit planes, in one
+    :func:`repro.kernels.ops.batched_walk` launch; the operand is kept in
+    ``planes`` under one key per direction."""
     from repro.kernels import ops as K
 
     key = (direction, tuple((op.op_id, slot) for op, slot in chain))
@@ -453,7 +482,8 @@ def _fused_walk(index: ProvenanceIndex, src: str, dst: str, rows_batch,
 
 def _gather_walk(index: ProvenanceIndex, chain, stack: np.ndarray, dst: str,
                  direction: str, use_pallas: Optional[bool],
-                 max_plane_bytes: int, counters: Optional[Dict[str, int]],
+                 max_plane_bytes: int, table_bytes: int,
+                 counters: Optional[Dict[str, int]],
                  planes: Optional[WalkPlaneStore], per_probe: bool):
     """The fused walk of a chain of structured hops: its composed row map
     (:func:`repro.core.compose.chain_gather`), resident in ``planes`` under
@@ -462,18 +492,20 @@ def _gather_walk(index: ProvenanceIndex, chain, stack: np.ndarray, dst: str,
     device as row ids and the answer comes back as rows, so nothing is
     packed on the host, and with ``per_probe`` the answer is each probe's
     rows, with no ``(B, n_dst)`` stack made.  None when the answer table
-    would exceed :data:`repro.kernels.ops.GATHER_TABLE_BYTES`.  With a
-    counter dict, a plan adds its probe rows to ``gather_walk_rows``, the
-    answer rows the table held to ``gather_walk_hits`` and the map's links
-    to ``gather_walk_links``."""
+    would exceed ``table_bytes``: before any lookup or put where even a
+    table one lane wide would.  With a counter dict, a plan adds its probe
+    rows to ``gather_walk_rows``, the answer rows the table held to
+    ``gather_walk_hits`` and the map's links to ``gather_walk_links``."""
     from repro.core.compose import chain_gather
     from repro.kernels import ops as K
 
-    key = ("gather", tuple((op.op_id, slot) for op, slot in chain))
-    gmap = planes.get(key) if planes is not None else None
     probe, rows = mask_rows(stack)
     # a few padded sizes, so that each compiles once
     q = max(K.GATHER_BLOCK_Q, 1 << (len(rows) - 1).bit_length())
+    if q * K.LANES * 4 > table_bytes:
+        return None
+    key = ("gather", tuple((op.op_id, slot) for op, slot in chain))
+    gmap = planes.get(key) if planes is not None else None
     queries = np.full(q, -1, dtype=np.int32)
     queries[:len(rows)] = rows
     put_bytes = queries.nbytes
@@ -490,7 +522,7 @@ def _gather_walk(index: ProvenanceIndex, chain, stack: np.ndarray, dst: str,
     if counters is not None:
         counters["walk_put_bytes"] += put_bytes
     deg = (gmap.fwd if direction == "fwd" else gmap.bwd)[2]
-    if q * deg * 4 > K.GATHER_TABLE_BYTES:
+    if q * deg * 4 > table_bytes:
         return None
     table_dev = K.gather_walk(queries_dev, gmap, direction)
     with span("walk.wait", counters, "walk_wait_ns"):

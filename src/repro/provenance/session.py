@@ -137,13 +137,16 @@ class QuerySession:
             "hopcache": 0,
             "fused_walk": 0,
             # fused walks answered from a composed row map on the device
-            # (chains of structured hops too large for dense planes)
+            # (chains whose every hop is structured)
             "gather_walk": 0,
             # and, over those walks, the probe rows looked up, the answer
             # rows found and the walked maps' links
             "gather_walk_rows": 0,
             "gather_walk_hits": 0,
             "gather_walk_links": 0,
+            # structured chains whose answer table was too large for the
+            # row map, walked through dense planes instead
+            "gather_walk_declined": 0,
             # linear chains the fused walk declined for size (host walk)
             "fused_walk_oversize": 0,
             "meta": 0,

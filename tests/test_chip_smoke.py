@@ -49,10 +49,39 @@ def test_tpcdi_phase_matches_reference(smoke, monkeypatch):
     assert line["hopcache"]["entries_structured"] == 2
 
 
+@pytest.fixture(scope="module")
+def compas(smoke):
+    index, src, sink = smoke.capture_usecase("compas")
+    plans = smoke.record_plans(index, src, sink, np.random.default_rng(0), 8)
+    return index, plans
+
+
+def test_compas_record_plans_pass_the_gather_route_check(smoke, compas):
+    index, plans = compas
+    routed = smoke.check_gather_route(index, plans, smoke.reference(index),
+                                      fused_walk=True)
+    assert routed == {"fused_walk": 2, "gather_walk": 2,
+                      "gather_walk_declined": 0, "fused_walk_oversize": 0}
+
+
+def test_gather_route_check_fails_on_the_dense_route(smoke, compas):
+    index, plans = compas
+    op = index.ops[-1]
+    structured = op.tensor
+    op.tensor = structured.as_coo()     # one hop with no structure
+    try:
+        with pytest.raises(RuntimeError, match="gather walk did not answer"):
+            smoke.check_gather_route(index, plans, smoke.reference(index),
+                                     fused_walk=True)
+    finally:
+        op.tensor = structured
+
+
 def test_fused_walk_phase_routes_to_fused_kernel(smoke):
     line = smoke.phase_fused_walk(n=512, n_ops=8, count=8, fused_walk=True)
     assert line["hops"] == 8
     assert line["session"]["fused_walk"] == 2
+    assert "gather_walk" not in line["session"]  # the hops have no structure
     assert line["plane_bytes"] == 8 * 512 * 512 // 8
 
 

@@ -1,5 +1,5 @@
-"""The fused walk's gather route: chains of structured hops whose dense
-planes exceed the cap walk their composed row map on the device.
+"""The fused walk's gather route: chains of structured hops walk their
+composed row map on the device, whatever their dense planes would take.
 
 Pins (a) that the route answers byte for byte as the per-op walkers
 (``forward_``/``backward_record_masks_batch``) over joins probed on either
@@ -14,7 +14,10 @@ per chain, serving both directions, with the plane store's counters; and
 (d) the route reached through ``prov(...)`` and a session at a size over
 the default cap, with its counters and spans; and (e) the word scan that
 finds the probe stack's set entries, and the per-probe answers the route
-gives without a dense stack (checked beside every stack it answers).
+gives without a dense stack (checked beside every stack it answers); and
+(f) the route's choice under the default cap: structured chains take the
+row map, unstructured ones and structured ones whose answer table is too
+large the dense planes, and the Table VIII COMPAS chain the row map.
 """
 from collections import defaultdict
 
@@ -24,9 +27,11 @@ import pytest
 
 import pipegen
 from repro.core import query as Q
+from repro.core.compose import path_tensors
 from repro.core.pipeline import ProvenanceIndex
 from repro.dataprep.table import Table
 from repro.dataprep.tracked import track
+from repro.dataprep.usecases import USECASES
 from repro.kernels import ops as K
 from repro.kernels import ref as R
 from repro.provenance import QuerySession, prov
@@ -39,14 +44,14 @@ def _table(rng, n, key, keys):
     })
 
 
-def _join(how="inner", dup=False, filters=False, append=False, seed=0):
+def _join(how="inner", dup=False, filters=False, append=False, seed=0,
+          n_dim=90, n_fact=70):
     """dim [-> filter] [-> append] ; fact.join(dim) [-> filter] -> sink.
     ``dup`` draws fact keys with replacement (many joined rows per dim
     row); keys absent from dim leave fact rows dangling (left, outer), and
     dim rows no fact names dangle (outer)."""
     rng = np.random.default_rng(seed)
     idx = ProvenanceIndex(f"gather-{how}-{dup}-{filters}-{append}-{seed}")
-    n_dim, n_fact = 90, 70
     dim = track(_table(rng, n_dim, "k", np.arange(n_dim)), idx, "dim")
     if filters:
         dim = dim.filter_rows(rng.random(n_dim) > 0.2)
@@ -189,6 +194,155 @@ def test_unstructured_chains_over_the_cap_stay_on_the_host():
         counters=counters) is None
     assert counters["fused_walk_oversize"] == 1
     assert counters["gather_walk"] == 0
+
+
+# -- the route's choice under the default cap ---------------------------------
+def _wide(seed=0):
+    """filter -> left join on repeated keys -> filter, over 1,500 dimension
+    and 1,400 fact rows: 3 x 1,500^2 bits of planes (844 KB), under the
+    default cap and over a sparse plan's answer table (64 KiB)."""
+    return _join("left", dup=True, filters=True, seed=seed, n_dim=1500,
+                 n_fact=1400)
+
+
+def _sparse(rng, batch, n):
+    rows = rng.random((batch, n)) < 0.005
+    rows[0] = False                     # an empty probe
+    return rows
+
+
+def _plane_bytes(idx, src, dst, direction):
+    up, down = (src, dst) if direction == "fwd" else (dst, src)
+    chain = path_tensors(idx, up, down)
+    n = max(max(op.tensor.n_in[s], op.tensor.n_out) for op, s in chain)
+    return len(chain) * n * n // 8
+
+
+def _dense_launches():
+    return K.launch_counts().get("batched_walk", 0)
+
+
+@pytest.mark.parametrize("per_probe", [False, True])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_structured_chains_under_the_cap_take_the_gather_route(direction,
+                                                               per_probe):
+    idx, sink = _wide()
+    src, dst = ("dim", sink) if direction == "fwd" else (sink, "dim")
+    assert 64 << 10 < _plane_bytes(idx, src, dst, direction) <= 256 << 20
+    rows = _sparse(np.random.default_rng(6), 8, idx.datasets[src].n_rows)
+    counters = defaultdict(int)
+    before = _dense_launches()
+    got = Q.fused_walk_record_masks_batch(idx, src, dst, rows, direction,
+                                          counters=counters,
+                                          per_probe=per_probe)
+    assert _dense_launches() == before
+    assert counters["gather_walk"] == 1
+    assert counters["gather_walk_declined"] == 0
+    assert counters["fused_walk_oversize"] == 0
+    want = _walker(idx, src, dst, rows, direction)
+    if per_probe:
+        assert len(got) == len(want)
+        for answer, row in zip(got, want):
+            np.testing.assert_array_equal(answer, np.flatnonzero(row))
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_unstructured_chains_under_the_cap_launch_the_dense_walk(direction):
+    idx, sink = _wide()
+    op = idx.ops[idx.producer[sink]]
+    op.tensor = op.tensor.as_coo()      # the same relation, no structure
+    src, dst = ("dim", sink) if direction == "fwd" else (sink, "dim")
+    rows = _sparse(np.random.default_rng(7), 8, idx.datasets[src].n_rows)
+    counters = defaultdict(int)
+    before = _dense_launches()
+    got = Q.fused_walk_record_masks_batch(idx, src, dst, rows, direction,
+                                          counters=counters, per_probe=True)
+    assert _dense_launches() == before + 1
+    for k in ("gather_walk", "gather_walk_declined", "fused_walk_oversize"):
+        assert counters[k] == 0, k
+    np.testing.assert_array_equal(got, _walker(idx, src, dst, rows,
+                                               direction))
+
+
+# structured chains whose answer table the route declines: (a) over a
+# 90-row join's 1 KB of planes, whatever the plan; (b) key 7's 2,000 rows
+# widen the forward table to 2,048 lanes (1 MiB for 128 probe rows), over
+# the join's 845 KB of planes; (c) over a table cap patched to 1 KiB
+DECLINED = [("small_planes", "fwd"), ("small_planes", "bwd"),
+            ("heavy_key", "fwd"), ("table_cap", "fwd"), ("table_cap", "bwd")]
+
+
+@pytest.mark.parametrize("case,direction", DECLINED)
+def test_declined_structured_chains_go_dense_under_the_cap(case, direction,
+                                                           monkeypatch):
+    if case == "table_cap":
+        monkeypatch.setattr(K, "GATHER_TABLE_BYTES", 1024)
+    idx, sink = {"small_planes": lambda: _join("outer", dup=True),
+                 "heavy_key": _heavy_join, "table_cap": _wide}[case]()
+    src, dst = ("dim", sink) if direction == "fwd" else (sink, "dim")
+    rows = _probes(np.random.default_rng(8), 4, idx.datasets[src].n_rows)
+    counters = defaultdict(int)
+    before = _dense_launches()
+    got = Q.fused_walk_record_masks_batch(idx, src, dst, rows, direction,
+                                          counters=counters)
+    assert _dense_launches() == before + 1
+    assert counters["gather_walk_declined"] == 1
+    assert counters["gather_walk"] == counters["fused_walk_oversize"] == 0
+    want = _walker(idx, src, dst, rows, direction)
+    np.testing.assert_array_equal(got, want)
+    # over the cap only the route's own table cap binds; past it, with no
+    # dense route left, the host walker answers
+    counters = defaultdict(int)
+    got = Q.fused_walk_record_masks_batch(idx, src, dst, rows, direction,
+                                          max_plane_bytes=0,
+                                          counters=counters)
+    assert counters["gather_walk_declined"] == 0
+    if case == "table_cap":
+        assert got is None
+        assert counters["fused_walk_oversize"] == 1
+        assert counters["gather_walk"] == 0
+    else:
+        np.testing.assert_array_equal(got, want)
+        assert counters["gather_walk"] == 1
+        assert counters["fused_walk_oversize"] == 0
+
+
+def test_compas_record_plans_take_the_gather_route():
+    """The Table VIII COMPAS chain (7 structured ops, 7 x 7,214^2 bits of
+    planes under the cap): every record plan of a session with the fused
+    walk on, both ways, answers from the row map as the host walker."""
+    make, run = USECASES["compas"]
+    idx = ProvenanceIndex("compas-route")
+    sink = run(idx, make()).dataset_id
+    src = "compas_src"
+    assert _plane_bytes(idx, src, sink, "fwd") <= 256 << 20
+    sess = QuerySession(idx, use_hopcache=False, fused_walk=True)
+    host = QuerySession(idx, use_hopcache=False, fused_walk=False)
+    rng = np.random.default_rng(9)
+    before = _dense_launches()
+    n_plans = 0
+    for batch, size in ((64, 4), (8, 1)):
+        for direction, a, b in (("fwd", src, sink), ("bwd", sink, src)):
+            n = idx.datasets[a].n_rows
+            rows = [np.sort(rng.choice(n, size=size, replace=False))
+                    for _ in range(batch)]
+            q = prov(idx).source(a).rows_batch(rows)
+            q = q.forward() if direction == "fwd" else q.backward()
+            plan = q.to(b).plan()
+            got, want = sess.run(plan), host.run(plan)
+            assert len(got) == len(want) == batch
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(g, w)
+                assert g.dtype == w.dtype
+            n_plans += 1
+    c = sess.counters
+    assert c["plans"] == c["fused_walk"] == c["gather_walk"] == n_plans
+    assert c["gather_walk_declined"] == c["fused_walk_oversize"] == 0
+    assert _dense_launches() == before
+    # one resident row map answers both directions
+    assert (c["walk_plane_misses"], c["walk_plane_hits"]) == (1, n_plans - 1)
 
 
 # -- the kernel against its oracle -------------------------------------------

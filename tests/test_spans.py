@@ -10,6 +10,7 @@ tier's pass and queue-wait counters over a counting backend, and (e) that
 the federation's ``run_many``, which shares ``run_many_fused`` with the
 session, still answers and writes no new counter.
 """
+import contextlib
 import glob
 import os
 import threading
@@ -20,6 +21,7 @@ import pytest
 
 import pipegen
 import test_query_parity as tqp
+from repro.core.capture import force_coo_capture
 from repro.core.compose import path_tensors
 from repro.core.pipeline import ProvenanceIndex
 from repro.core.provtensor import pack_bitplane
@@ -33,17 +35,19 @@ TIMEOUT_S = 60
 WALK_SPANS = ("session.execute", "walk.fused", "walk.put", "walk.wait")
 
 
-def _chain(n=70, seed=0):
+def _chain(n=70, seed=0, coo_filter=False):
     """raw -> scaled -> sink: a linear chain, so record plans take the
-    fused walk."""
+    fused walk; with ``coo_filter`` the filter is captured as COO, so they
+    take its dense planes."""
     rng = np.random.default_rng(seed)
     idx = ProvenanceIndex(f"spans-{seed}")
     t = track(Table.from_columns({
         "k": np.arange(n, dtype=np.float32),
         "x": rng.normal(size=n).astype(np.float32),
     }), idx, "raw")
-    sink = t.value_transform("x", "scale", factor=2.0) \
-        .filter_rows(rng.random(n) > 0.3)
+    scaled = t.value_transform("x", "scale", factor=2.0)
+    with force_coo_capture() if coo_filter else contextlib.nullcontext():
+        sink = scaled.filter_rows(rng.random(n) > 0.3)
     sink.mark_sink()
     return idx, sink.dataset_id
 
@@ -134,7 +138,7 @@ def test_walk_put_bytes_are_the_planes_and_masks_put():
     """Every walk puts its packed mask; the planes go only with the first
     walk of each direction, after which the session's plane store holds
     them on the device."""
-    idx, sink = _chain()
+    idx, sink = _chain(coo_filter=True)
     sess = _session(idx)
     plans = _plans(idx, sink, 4, batch=5)
     expected = 0

@@ -5,8 +5,9 @@ for a described ``v5e:2x2`` topology and refuses what the chip would refuse
 (block tiling, unsupported primitives, scoped VMEM, device memory), which
 interpret-mode parity tests never see.  Shapes are the widths the chip
 smoke (``chip_smoke.py``) runs: the Table VIII use cases, the fused walk
-at its 256 MiB plane cap, and the gather walk over the TPC-DI SF3 join.
-Nothing runs, so these say nothing about results or times.
+at its 256 MiB plane cap, and the gather walk over the Compas chain and
+the TPC-DI SF3 join.  Nothing runs, so these say nothing about results or
+times.
 """
 import os
 
@@ -146,6 +147,20 @@ def test_gather_walk_compiles_for_v5e(one_chip, no_compile_cache, n_probe,
     q = 64 * 4 if max_deg == 128 else K.GATHER_BLOCK_Q  # B = 64 probes of 4
     _compile_lineage_gather(one_chip, q, n_probe, n_links, max_deg,
                             "gather_walk")
+
+
+@pytest.mark.parametrize("q", [
+    K.GATHER_BLOCK_Q,         # up to 128 probe rows: the serving tier's plans
+    64 * 4,                   # B = 64 probes of 4
+])
+@pytest.mark.parametrize("n_probe", [
+    7214,                     # Compas fwd: source rows, the inverse map
+    6907,                     # Compas bwd: sink rows, the map
+])
+def test_gather_walk_compiles_for_v5e_at_compas_shapes(one_chip,
+                                                       no_compile_cache,
+                                                       n_probe, q):
+    _compile_lineage_gather(one_chip, q, n_probe, 6907, 128, "gather_walk")
 
 
 @pytest.mark.parametrize("rows,nnz,max_deg", [

@@ -7,7 +7,10 @@ directions, on the oracle and the interpret-mode Pallas path; (c) the
 store's counters and the bytes a hit puts (the mask alone); (d) LRU
 eviction and an operand over the budget, under a patched budget; (e) that
 the linearity audit still runs before any lookup; (f) that a chain whose
-tensors were spilled and faulted back still hits.
+tensors were spilled and faulted back still hits.  Every chain here has a
+hop captured as explicit COO, so the fused walk routes it to the dense
+planes (a chain of structured hops walks its row map instead:
+``test_gather_walk.py``).
 """
 from collections import defaultdict
 
@@ -17,6 +20,7 @@ import pytest
 
 import pipegen
 from repro.core import query as Q
+from repro.core.capture import force_coo_capture
 from repro.core.pipeline import ProvenanceIndex
 from repro.core.provtensor import pack_bitplane
 from repro.core.spill import SpillPolicy, _TensorFault
@@ -31,14 +35,16 @@ PLANE_COUNTERS = ("walk_plane_hits", "walk_plane_misses",
 
 
 def _chain(n=60, seed=0, index=None):
-    """raw -> kept -> scaled: a linear chain, so record plans fuse."""
+    """raw -> kept -> scaled: a linear chain, so record plans fuse; the
+    filter is captured as COO, so they take the dense planes."""
     rng = np.random.default_rng(seed)
     idx = index if index is not None else ProvenanceIndex(f"planes-{seed}")
     t = track(Table.from_columns({
         "k": np.arange(n, dtype=np.float32),
         "x": rng.normal(size=n).astype(np.float32),
     }), idx, "raw")
-    kept = t.filter_rows(rng.random(n) > 0.3)
+    with force_coo_capture():
+        kept = t.filter_rows(rng.random(n) > 0.3)
     sink = kept.value_transform("x", "scale", factor=2.0)
     sink.mark_sink()
     return idx, kept, sink
@@ -85,7 +91,8 @@ def test_prepared_walk_matches_the_one_shot_walk(hops, use_pallas):
 @pytest.mark.parametrize("seed", range(6))
 @pytest.mark.parametrize("use_pallas", [None, True])
 def test_stored_planes_answer_as_fresh_ones(seed, use_pallas):
-    idx, sink, rng = pipegen.random_pipeline(seed)
+    with force_coo_capture():  # no structured hop: the dense route
+        idx, sink, rng = pipegen.random_pipeline(seed)
     counters = defaultdict(int)
     store = Q.WalkPlaneStore(counters)
     for direction, (src, dst) in (("fwd", ("src", sink)),
@@ -102,6 +109,7 @@ def test_stored_planes_answer_as_fresh_ones(seed, use_pallas):
                 continue
             np.testing.assert_array_equal(kept, fresh)
     assert counters["walk_plane_hits"] == counters["walk_plane_misses"] > 0
+    assert counters["gather_walk"] == counters["gather_walk_declined"] == 0
 
 
 def test_a_hit_puts_only_the_mask():
