@@ -12,14 +12,10 @@ response all the way back to the RAW source row across the index boundary:
 one plan, split at the boundary, one cost-model-routed pass per side.
 """
 import numpy as np
-import jax
-import jax.numpy as jnp
 
-from repro.configs.registry import get_smoke_config
 from repro.core.pipeline import ProvenanceIndex
 from repro.dataprep.table import Table
 from repro.dataprep.tracked import track
-from repro.models.registry import get_model
 from repro.provenance import CapabilityError
 from repro.serve.engine import ServeEngine
 
@@ -48,20 +44,16 @@ except CapabilityError:
     print("capability: prep index is read-only from the serving tier")
 
 # --- the serving tier attaches upstream provenance via the handle -------------
-cfg = get_smoke_config("gemma3-1b")
-model = get_model(cfg)
-params = model.init_params(cfg, jax.random.PRNGKey(0))
-
-B, SP, NEW = 4, 8, 6
-prompts = rng.integers(1, cfg.vocab, (B, SP)).astype(np.int32)
-engine = ServeEngine(cfg, params, max_seq=SP + NEW, dtype=jnp.float32,
-                     upstream=handle)
+B, SP, NEW, VOCAB = 4, 8, 6, 32_000
+# the (B, NEW) response tokens the serving stack's model returned for B
+# prompts of SP tokens (a seeded stand-in here)
+tokens = rng.integers(1, VOCAB, (B, NEW)).astype(np.int32)
+engine = ServeEngine(upstream=handle)
 
 # each request serves a row of the CLEAN dataset: request_ids are the row
 # alignment across the boundary link
 request_rows = np.array([0, 3, 3, 5]) % clean.table.n_rows
-result = engine.generate(prompts, n_new=NEW, request_ids=request_rows,
-                         record_provenance=True)
+result = engine.record(tokens, prompt_len=SP, request_ids=request_rows)
 print("recorded:", result.request_dataset, "->", result.response_dataset,
       "| catalog:", engine.catalog)
 

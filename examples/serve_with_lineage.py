@@ -2,13 +2,13 @@
 
     PYTHONPATH=src python examples/serve_with_lineage.py
 
-Serves a small decoder LM (smoke-size gemma3 family: exercises the
-local:global interleave + ring caches on the decode path) over a batch of
-requests.  The engine owns its OWN provenance index wrapped in a
-single-entry :class:`ProvCatalog` (``engine.catalog``): serving-local
-lineage routes through the index's shared ``QuerySession`` exactly as
-before, and the same catalog is where an upstream data-prep boundary
-attaches (``upstream=prep_index.export(...)`` — see
+Records a batch of served requests: the serving stack's own model
+produced the response tokens (a seeded stand-in array here), and the
+engine records each response row's request row.  The engine owns its OWN
+provenance index wrapped in a single-entry :class:`ProvCatalog`
+(``engine.catalog``): serving-local lineage routes through the index's
+shared ``QuerySession``, and the same catalog is where an upstream
+data-prep boundary attaches (``upstream=prep_index.export(...)`` — see
 ``examples/federated_lineage.py`` for the cross-index trace-to-source
 flow).  The legacy ``prov_index=`` attach is deprecated.
 
@@ -19,33 +19,26 @@ fuse key into fused ``run_many`` passes, and admission scopes each tenant
 to a capability ref set.
 """
 import numpy as np
-import jax
-import jax.numpy as jnp
 
-from repro.configs.registry import get_smoke_config
-from repro.models.registry import get_model
 from repro.provenance import prov
 from repro.serve import ServingTier
 from repro.serve.engine import ServeEngine
 
-cfg = get_smoke_config("gemma3-1b")
-model = get_model(cfg)
-params = model.init_params(cfg, jax.random.PRNGKey(0))
-
-B, SP, NEW = 4, 8, 6
+B, SP, NEW, VOCAB = 4, 8, 6, 32_000
 rng = np.random.default_rng(1)
-prompts = rng.integers(1, cfg.vocab, (B, SP)).astype(np.int32)
+# the (B, NEW) response tokens of B prompts of SP tokens each, as the
+# serving stack's model would return them
+tokens = rng.integers(1, VOCAB, (B, NEW)).astype(np.int32)
 
-engine = ServeEngine(cfg, params, max_seq=SP + NEW, dtype=jnp.float32)
+engine = ServeEngine()
 # the engine's serving index is registered in its catalog under "serve";
 # the shared session's cost model routes per plan — cheap adjacent
 # (response -> request) hops stay on the walk, and sustained probe demand
 # against a distant pair amortizes a composition and flips to the hop-cache
 print("catalog:", engine.catalog)
-result = engine.generate(prompts, n_new=NEW,
-                         request_ids=np.array([101, 102, 103, 104]),
-                         record_provenance=True)
-print("generated tokens:\n", result.tokens)
+result = engine.record(tokens, prompt_len=SP,
+                       request_ids=np.array([101, 102, 103, 104]))
+print("served tokens:\n", result.tokens)
 print("recorded:", result.request_dataset, "->", result.response_dataset)
 
 # --- per-request lineage through the shared session ----------------------------

@@ -21,11 +21,6 @@ against the CPU container; this module MEASURES them on the actual backend:
   ``ComposedIndex(backend="auto")`` and ``QuerySession._strategy`` then
   run on measured numbers, and ``explain()`` reports their provenance.
 
-The machine roofline terms (peak FLOPs / HBM / VPU word-op rate) ride in
-the same file so ``bench_compose_roofline`` and the cost model can never
-disagree about the machine; they keep their v5e defaults until a real-TPU
-pass overwrites them.
-
 Run directly::
 
     PYTHONPATH=src python -m repro.core.calibrate [--full] [--path FILE]
@@ -166,7 +161,7 @@ def fit_constants(meas: Dict[str, object],
                   base: Optional[Constants] = None) -> Constants:
     """Fit routing constants from :func:`run_microbench` output.
 
-    Non-measured constants (walk dispatch, stitch, machine roofline terms)
+    Non-measured constants (walk dispatch, stitch)
     carry over from ``base`` (default: the uncalibrated defaults).
     """
     base = base or Constants()

@@ -20,8 +20,8 @@ though the CSR backend's real cost scales with nnz.  This module centralizes
   amortize a composition the old heuristic never attempted.
 
 Cost units are *estimated nanoseconds on the host*; only ratios matter, the
-constants below were calibrated once against ``benchmarks/bench_query.py``
-on the CPU container and are deliberately coarse.
+defaults below were fitted once on a host CPU and are deliberately coarse
+(:mod:`repro.core.calibrate` fits a measured set on the actual backend).
 """
 from __future__ import annotations
 
@@ -80,20 +80,10 @@ DENSITY_THRESHOLD = 0.06
 # term the fused batched-walk kernel pays ONCE instead of K×3 times.
 C_LAUNCH_OVERHEAD = 50_000.0
 
-# Machine roofline terms (TPU v5e defaults): shared with
-# benchmarks/bench_compose_roofline.py via Constants so the roofline and the
-# cost model can never disagree about the machine.
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
-# VPU: 8 cores x (8,128) lanes x ~940 MHz ~= 1e12 lane-ops/s; each uint32
-# lane-op retires 32 boolean MACs in the bitplane kernel.
-VPU_WORD_OPS = 0.96e12
-
 
 @dataclasses.dataclass(frozen=True)
 class Constants:
-    """One coherent set of cost/roofline constants, with provenance.
+    """One coherent set of cost constants, with provenance.
 
     The module-level ``C_*`` literals above stay the uncalibrated defaults
     (``Constants()`` reproduces them bit-for-bit); ``repro.core.calibrate``
@@ -116,11 +106,6 @@ class Constants:
     c_stitch_overhead: float = C_STITCH_OVERHEAD
     c_launch_overhead: float = C_LAUNCH_OVERHEAD
     density_threshold: float = DENSITY_THRESHOLD
-    # machine roofline terms (satellite of the calibration file)
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    link_bw: float = LINK_BW
-    vpu_word_ops: float = VPU_WORD_OPS
     # provenance: where these numbers came from
     source: str = "default"       # "default" | "calibrated"
     device: str = ""              # device kind the calibration ran on

@@ -87,8 +87,7 @@ def compose_sharded(a_bits: jax.Array, b_bits: jax.Array, mesh: Mesh) -> jax.Arr
 
 def _bitmm(a_bits: jax.Array, b_bits: jax.Array) -> jax.Array:
     """(OR,AND) matmul on packed operands, jnp path (Pallas on real TPU via
-    repro.kernels.ops.bitmatmul; the jnp form lowers on any backend and is
-    what the dry-run compiles)."""
+    repro.kernels.ops.bitmatmul; the jnp form lowers on any backend)."""
     m, kw = a_bits.shape
     k, nw = b_bits.shape
     a = kref.unpack_bits(a_bits, kw * 32)[:, :k].astype(jnp.float32)  # (m, k)

@@ -224,7 +224,7 @@ class ProvenanceIndex:
         """The index's shared :class:`~repro.provenance.session.QuerySession`
         — the planner/executor behind ``repro.provenance.prov(index)`` and
         the legacy ``q1``-``q11`` shims.  It wraps :meth:`composed`, so every
-        caller (examples, serving tier, benchmarks) probes the same composed
+        caller (examples, the serving tier, ``bench/``) probes the same composed
         relations.  Pass kwargs (e.g. ``hopcache_min_batch``) on first call
         to configure it."""
         from repro.provenance import QuerySession  # circular at module scope

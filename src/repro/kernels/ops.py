@@ -50,8 +50,8 @@ def on_tpu() -> bool:
 
 # -- launch accounting --------------------------------------------------------
 # Every public kernel entry counts ONE device dispatch (the Pallas launch on
-# TPU, the equivalent jit'd oracle call elsewhere).  bench_compose_roofline
-# asserts the fused walk's K×3 -> 1 launch reduction off these counters.
+# TPU, the equivalent jit'd oracle call elsewhere).  The tests assert the
+# fused walk's K×3 -> 1 launch reduction off these counters.
 # Compiled (not interpreted) Pallas launches are counted again on their own,
 # so a run can show that the device kernels, not their oracles, answered.
 _LAUNCHES: dict = {}

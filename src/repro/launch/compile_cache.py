@@ -1,8 +1,7 @@
 """Where entry points keep JAX's persistent compilation cache.
 
-Entry points (``chip_smoke.py``, the benchmark scripts) call
-:func:`enable_compile_cache` before their first compile; importing the
-library sets nothing.
+Entry points (``chip_smoke.py``) call :func:`enable_compile_cache` before
+their first compile; importing the library sets nothing.
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
-"""Serving: the batched generation engine and the async query tier.
+"""Serving: the lineage recorder and the async query tier.
 
-* :class:`~repro.serve.engine.ServeEngine` — prefill/decode generation with
-  request-level provenance capture (one engine, one model);
+* :class:`~repro.serve.engine.ServeEngine` — records each served batch's
+  (response -> request) provenance and answers lineage over it, across an
+  upstream data-prep boundary when one is attached;
 * :class:`~repro.serve.tier.ServingTier` — the async micro-batching front
   door that fuses lineage queries across requests and tenants into single
   ``run_many`` passes, with bounded admission and per-tenant capability

@@ -1,11 +1,12 @@
-"""Batched serving engine: prefill + decode with a jitted step.
+"""Serving lineage recorder: request-level provenance of served responses.
 
-The serving counterpart of the trainer: holds the KV cache (or SSD state)
-for a batch of requests, advances them one token per jitted ``serve_step``,
-and traces every emitted token back to its REQUEST RECORD — record-level
-why-provenance of the serving path, captured with the same ProvTensor
-machinery as the data pipeline (each generated token derives from its
-request row: an identity-tensor-per-step collapsed to one HAUGMENT link).
+A serving stack runs its model however it likes; :class:`ServeEngine`
+records what it served.  :meth:`ServeEngine.record` takes one batch's
+``(B, n_new)`` response tokens and traces every response row back to its
+REQUEST RECORD — record-level why-provenance of the serving path, captured
+with the same ProvTensor machinery as the data pipeline (each generated
+token derives from its request row: an identity-tensor-per-step collapsed
+to one HAUGMENT link).
 
 The engine owns its OWN :class:`ProvenanceIndex` (the serving pipeline's)
 and a :class:`~repro.provenance.catalog.ProvCatalog` around it.  Upstream
@@ -19,13 +20,13 @@ data-preparation provenance attaches through ``upstream=``:
   index into an EXISTING catalog and uses that qualified ref as the
   boundary.
 
-``generate(..., record_provenance=True)`` registers the
-(response -> request) op; :meth:`response_lineage` /
-:meth:`response_lineage_batch` compile to :class:`QueryPlan`\\ s — serving-
-local targets route through the index's shared ``QuerySession`` (ONE
-composed relation per endpoint pair), upstream targets route through the
-catalog's :class:`~repro.provenance.federation.FederatedSession`, tracing
-responses all the way back to raw prep sources across the boundary.
+``record(...)`` registers the (response -> request) op;
+:meth:`response_lineage` / :meth:`response_lineage_batch` compile to
+:class:`QueryPlan`\\ s — serving-local targets route through the index's
+shared ``QuerySession`` (ONE composed relation per endpoint pair), upstream
+targets route through the catalog's
+:class:`~repro.provenance.federation.FederatedSession`, tracing responses
+all the way back to raw prep sources across the boundary.
 
 The legacy ``prov_index=`` attach — handing the engine the whole prep
 index to record into — is DEPRECATED (it grants the serving tier mutation
@@ -35,19 +36,14 @@ single-entry catalog, and warns once per process.
 from __future__ import annotations
 
 import dataclasses
-import sys
 import warnings
-from typing import Any, List, Optional, Set, Tuple
+from typing import List, Optional, Set, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
 from repro.core.opcat import AttrMap, CaptureInfo, OpCategory
 from repro.core.pipeline import ProvenanceIndex
 from repro.dataprep.table import Table
-from repro.models.registry import get_model
 from repro.provenance.catalog import (
     BoundaryHandle,
     ProvCatalog,
@@ -61,56 +57,28 @@ _DEPRECATION_WARNED: Set[str] = set()
 
 
 def _warn_once(key: str, message: str) -> None:
-    """Once-per-process deprecation (the q1-q11 shim pattern).
-
-    The stacklevel is computed, not hardcoded: the ``prov_index=`` path
-    reaches here through ``__init__`` → ``_init_provenance`` (level 4)
-    while tests drive ``_init_provenance`` directly (level 3) — a fixed
-    level points one of the two at an engine-internal frame instead of the
-    caller's ``ServeEngine(...)`` line.  Walking out of this module's
-    frames attributes the warning to the first external call site on
-    either path."""
+    """Once-per-process deprecation (the q1-q11 shim pattern), attributed
+    to the caller's ``ServeEngine(...)`` line."""
     if key in _DEPRECATION_WARNED:
         return
     _DEPRECATION_WARNED.add(key)
-    level, frame = 2, sys._getframe(1)
-    while frame.f_back is not None and frame.f_globals.get("__name__") == __name__:
-        frame = frame.f_back
-        level += 1
-    warnings.warn(message, DeprecationWarning, stacklevel=level)
+    warnings.warn(message, DeprecationWarning, stacklevel=3)
 
 
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray        # (B, n_new)
     request_ids: np.ndarray   # (B,) provenance: emitted row -> request row
-    # set when the generation was recorded into the engine's index:
+    # set once the batch is recorded into the engine's index:
     request_dataset: Optional[str] = None
     response_dataset: Optional[str] = None
 
 
 class ServeEngine:
-    def __init__(self, cfg: ModelConfig, params: Any, max_seq: int,
-                 dtype=jnp.bfloat16,
-                 upstream=None,
-                 prov_index: Optional[ProvenanceIndex] = None):
-        self.cfg = cfg
-        self.params = params
-        self.max_seq = max_seq
-        self.dtype = dtype
-        self.model = get_model(cfg)
-        self._init_provenance(f"serve:{cfg.name}", upstream=upstream,
-                              prov_index=prov_index)
-        self._decode = jax.jit(
-            lambda p, tok, pos, cache: self.model.decode_step(cfg, p, tok, pos, cache,
-                                                              dtype=dtype)
-        )
+    """The serving index, its catalog, and lineage queries over both."""
 
-    # -- provenance wiring ------------------------------------------------------
-    def _init_provenance(self, name: str, upstream=None,
-                         prov_index: Optional[ProvenanceIndex] = None) -> None:
-        """Build the serving index + catalog.  Split out of ``__init__`` so
-        the capture path is testable without instantiating a model."""
+    def __init__(self, name: str = "serve", *, upstream=None,
+                 prov_index: Optional[ProvenanceIndex] = None) -> None:
         self._n_generations = 0
         self._upstream: Optional[Tuple[str, str]] = None  # (member, boundary ds)
         if prov_index is not None:
@@ -151,7 +119,7 @@ class ServeEngine:
                 and isinstance(upstream[0], ProvCatalog)):
             catalog, ref = upstream
             catalog.datasets[ref]   # resolve the DATASET now: a typo'd ref
-                                    # must fail here, not at first generate()
+                                    # must fail here, not at first record()
             serve_name, i = "serve", 2
             while serve_name in catalog.members:
                 serve_name, i = f"serve{i}", i + 1
@@ -174,83 +142,34 @@ class ServeEngine:
         """The catalog's shared FederatedSession (cross-index lineage)."""
         return self.catalog.session()
 
-    def generate(
-        self,
-        prompts: np.ndarray,           # (B, S_prompt) int32, -1 padded on the LEFT
-        n_new: int,
-        request_ids: Optional[np.ndarray] = None,
-        greedy: bool = True,
-        sample_seed: int = 0,          # greedy=False: PRNG seed (temperature 1)
-        frames: Optional[np.ndarray] = None,   # enc-dec: stub frontend output
-        record_provenance: bool = False,
-        request_source: Optional[str] = None,  # existing dataset the requests
-                                               # are rows of (else auto-added)
-    ) -> GenerationResult:
-        cfg = self.cfg
-        b, sp = prompts.shape
-        cache = self.model.init_cache(cfg, b, self.max_seq, dtype=self.dtype)
-        if cfg.is_encdec:
-            from repro.models import whisper as W
-            assert frames is not None, "enc-dec serving needs frames"
-            cache = W.encode_into_cache(cfg, self.params, jnp.asarray(frames, self.dtype),
-                                        cache)
-
-        toks = jnp.asarray(np.where(prompts < 0, 0, prompts), jnp.int32)
-        # prompt consumption token-by-token through the decode path (simple,
-        # exact; bulk prefill is the lowered prefill() used by the dry-run)
-        logits = None
-        for t in range(sp):
-            logits, cache = self._decode(self.params, toks[:, t], jnp.int32(t), cache)
-
-        # greedy: argmax.  greedy=False: temperature-1 categorical sampling
-        # with a SEEDED key split per step — deterministic for a given
-        # (params, prompts, sample_seed), the reproducibility contract the
-        # provenance record rests on.
-        key = jax.random.PRNGKey(sample_seed)
-
-        def _next_token(logits, key):
-            if greedy:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), key
-            key, sub = jax.random.split(key)
-            tok = jax.random.categorical(sub, logits.astype(jnp.float32),
-                                         axis=-1)
-            return tok.astype(jnp.int32), key
-
-        out = []
-        cur, key = _next_token(logits, key)
-        for i in range(n_new):
-            out.append(np.asarray(cur))
-            logits, cache = self._decode(self.params, cur, jnp.int32(sp + i), cache)
-            cur, key = _next_token(logits, key)
-
-        request_ids_given = request_ids is not None
-        if request_ids is None:
-            request_ids = np.arange(b, dtype=np.int64)
-        result = GenerationResult(
-            tokens=np.stack(out, axis=1),
-            request_ids=np.asarray(request_ids),
-        )
-        if record_provenance:
-            self._record_generation(result, prompt_len=sp, n_new=n_new,
-                                    request_source=request_source,
-                                    request_ids_given=request_ids_given)
-        return result
-
     # -- provenance capture ----------------------------------------------------
-    def _record_generation(self, result: GenerationResult, prompt_len: int,
-                           n_new: int, request_source: Optional[str],
-                           request_ids_given: bool = True) -> None:
-        """Register the (response row -> request row) HAUGMENT op.
+    def record(self, tokens, *, prompt_len: int, request_ids=None,
+               request_source: Optional[str] = None) -> GenerationResult:
+        """Record one served batch: the (response row -> request row)
+        HAUGMENT op over ``tokens``, the ``(B, n_new)`` response tokens the
+        caller's model produced for ``B`` requests of ``prompt_len`` prompt
+        tokens each.
 
-        With ``request_source`` the responses link to rows of an EXISTING
+        Without ``request_source`` the batch's requests become a fresh
+        ``requests@N`` dataset and its responses ``responses@N``.  With
+        ``request_source`` the responses link to rows of an EXISTING
         dataset of the serving index (``request_ids`` are row indices into
         it).  With an ``upstream=`` attach and no ``request_source``, the
-        fresh request dataset is LINKED to the upstream boundary through the
-        ``request_ids`` row alignment (each request row came from that
+        fresh request dataset is LINKED to the upstream boundary through
+        the ``request_ids`` row alignment (each request row came from that
         boundary row; ``-1`` marks a request with no upstream origin) —
         lineage then continues into the data-prep pipeline across the
         federation."""
-        b = result.tokens.shape[0]
+        tokens = np.asarray(tokens)
+        if tokens.ndim != 2:
+            raise ValueError(
+                f"tokens must be (B, n_new), got shape {tokens.shape}")
+        b, n_new = tokens.shape
+        request_ids_given = request_ids is not None
+        if request_ids is None:
+            request_ids = np.arange(b, dtype=np.int64)
+        result = GenerationResult(tokens=tokens,
+                                  request_ids=np.asarray(request_ids))
         # unique per INDEX, not per engine: several engines may share one
         # prov index (the documented pattern), or the index may already hold
         # earlier generations
@@ -260,9 +179,8 @@ class ServeEngine:
             gid += 1
         self._n_generations = gid + 1
         if request_source is None:
-            upstream = getattr(self, "_upstream", None)
             alignment = None
-            if upstream is not None:
+            if self._upstream is not None:
                 # the boundary link is a lineage ASSERTION — never fabricate
                 # it from the arange() default, and validate the alignment
                 # BEFORE any index mutation so a bad batch can't leave an
@@ -272,7 +190,7 @@ class ServeEngine:
                         "upstream-attached engines need explicit request_ids "
                         "(rows of the boundary dataset, -1 for requests with "
                         "no upstream origin) to record provenance")
-                up_name, boundary = upstream
+                up_name, boundary = self._upstream
                 n_up = self.catalog.datasets[qualify(up_name, boundary)].n_rows
                 alignment = np.asarray(result.request_ids, np.int64)
                 if alignment.size and (alignment.max() >= n_up
@@ -315,6 +233,7 @@ class ServeEngine:
         )
         result.request_dataset = req_ds
         result.response_dataset = resp_ds
+        return result
 
     # -- lineage queries (shared session / federation) ---------------------------
     def _lineage_target(self, dst: str) -> Tuple[bool, str]:
@@ -327,9 +246,8 @@ class ServeEngine:
             return False, dst
         if "/" in dst and dst in self.catalog.datasets:
             return True, dst
-        upstream = getattr(self, "_upstream", None)
-        if upstream is not None:
-            ref = qualify(upstream[0], dst)
+        if self._upstream is not None:
+            ref = qualify(self._upstream[0], dst)
             if ref in self.catalog.datasets:
                 return True, ref
         raise KeyError(
@@ -340,8 +258,7 @@ class ServeEngine:
         from repro.provenance import prov
 
         if result.response_dataset is None:
-            raise ValueError("generation was not recorded "
-                             "(generate(..., record_provenance=True))")
+            raise ValueError("batch was not recorded (ServeEngine.record)")
         federated, ref = self._lineage_target(dst)
         if not federated:
             qb = prov(self.prov).source(result.response_dataset)
@@ -389,11 +306,10 @@ class ServeEngine:
         from repro.provenance.impact import apply_invalidations, erasure_plan
 
         if source is None:
-            upstream = getattr(self, "_upstream", None)
-            if upstream is None:
+            if self._upstream is None:
                 raise ValueError(
                     "no upstream provenance attached; pass source=")
-            ref = qualify(*upstream)
+            ref = qualify(*self._upstream)
         else:
             _, ref = self._lineage_target(source)
             if "/" not in ref:

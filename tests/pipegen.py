@@ -27,13 +27,14 @@ from repro.provenance.catalog import qualify
 # ===========================================================================
 # Randomized pipelines over every op category
 # ===========================================================================
-def random_pipeline(seed, name="parity"):
+def random_pipeline(seed, name="parity", scale=1):
     """3-8 random ops over identity/vreduce/vaugment/hreduce/haugment/join/
     append, including outer joins and appends (``-1`` sentinels).  Returns
     ``(index, sink_dataset_id, rng)`` — the rng is advanced past the build
-    so callers draw independent probes."""
+    so callers draw independent probes.  ``scale`` multiplies the source's
+    15-49 rows (and with them the join key range)."""
     rng = np.random.default_rng(seed)
-    n = int(rng.integers(15, 50))
+    n = int(rng.integers(15, 50)) * scale
     K = max(3, n // 4)
     idx = ProvenanceIndex(f"{name}{seed}")
     t = Table.from_columns({

@@ -333,6 +333,57 @@ def test_session_cost_model_routing_counters():
     assert fresh.counters == {**fresh.counters, "hopcache": 1, "walk": 0}
 
 
+def test_every_routing_policy_answers_alike():
+    """The cost model, the legacy min-batch heuristic, a forced hop-cache
+    and a forced walk route the same workloads differently — a stream of
+    single probes, two large batches, and a mix — but answer them alike,
+    cold and once demand has settled the routes."""
+    idx, sink = _chain_index(n=400)
+    rng = np.random.default_rng(11)
+    n_src, n_sink = idx.datasets["src"].n_rows, idx.datasets[sink].n_rows
+    singles = [[int(r)] for r in rng.integers(0, n_src, 24)]
+    batch_f = [rng.integers(0, n_src, 4).tolist() for _ in range(64)]
+    batch_b = [rng.integers(0, n_sink, 4).tolist() for _ in range(64)]
+
+    def fwd(rows):
+        return prov(idx).source("src").rows(rows).forward().to(sink).plan()
+
+    workloads = {
+        "small_batch_stream": [fwd(r) for r in singles],
+        "large_batch": [
+            prov(idx).source("src").rows_batch(batch_f).forward().to(sink).plan(),
+            prov(idx).source(sink).rows_batch(batch_b).backward().to("src").plan(),
+        ],
+        "mixed": [fwd(r) for r in singles[::4]] + [
+            prov(idx).source(sink).rows(batch_b[0]).backward().to("src").plan(),
+            prov(idx).source("src").rows_batch(batch_f).forward().to(sink).plan(),
+        ],
+    }
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        policies = {
+            "auto": QuerySession(idx, ComposedIndex(idx)),
+            "heuristic_minbatch8": QuerySession(idx, ComposedIndex(idx),
+                                                hopcache_min_batch=8),
+            "forced_hopcache": QuerySession(idx, ComposedIndex(idx),
+                                            hopcache_min_batch=1),
+        }
+    walk = QuerySession(idx, ComposedIndex(idx), use_hopcache=False)
+    for name, plans in workloads.items():
+        want = [walk.run(p) for p in plans]
+        for sess in policies.values():
+            for _ in range(2):          # cold, then with demand settled
+                for got, w in zip([sess.run(p) for p in plans], want):
+                    if isinstance(w, list):
+                        assert len(got) == len(w)
+                        for g, x in zip(got, w):
+                            np.testing.assert_array_equal(g, x)
+                    else:
+                        np.testing.assert_array_equal(got, w)
+    assert policies["forced_hopcache"].counters["walk"] == 0
+    assert policies["auto"].counters["hopcache"] > 0
+
+
 def test_hopcache_min_batch_deprecated_but_honored():
     idx, sink = _chain_index()
     with pytest.warns(DeprecationWarning, match="hopcache_min_batch"):

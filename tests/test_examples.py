@@ -2,9 +2,7 @@
 
 API redesigns must not silently break the examples: each runs as a real
 ``python examples/<name>.py`` subprocess (CPU jax, tiny configs) and must
-exit 0 with its landmark output present.  ``train_with_provenance.py`` is
-excluded — it trains a real (if small) model and belongs to the manual
-tier; the serving example covers the model-bearing path at smoke size.
+exit 0 with its landmark output present.
 """
 import os
 import subprocess

@@ -40,7 +40,7 @@ from repro.provenance import (
 )
 from repro.provenance.catalog import Link, qualify, split_ref
 from repro.serve import engine as serve_engine
-from repro.serve.engine import GenerationResult, ServeEngine
+from repro.serve.engine import ServeEngine
 
 
 # ===========================================================================
@@ -488,20 +488,17 @@ def test_cross_relation_survives_unrelated_links():
     defeat the fast path in exactly the scenario it exists for."""
     pytest.importorskip("scipy")
     prep, exported, _, _ = _capability_fixture()
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:cachetest",
-                            upstream=prep.export(exported.dataset_id))
+    engine = ServeEngine("serve:cachetest",
+                         upstream=prep.export(exported.dataset_id))
     sess = engine.federation
     sess.cross_min_demand = 0
-    r1 = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                          request_ids=np.array([0, 1, 2]))
-    engine._record_generation(r1, prompt_len=2, n_new=2, request_source=None)
+    r1 = engine.record(np.zeros((3, 2), np.int32), prompt_len=2,
+                       request_ids=np.array([0, 1, 2]))
     got1 = engine.response_lineage(r1, rows=[1], upstream="raw")
     assert sess.counters["cross_composes"] == 1
     # a second generation appends a new link; the cached route keeps
-    r2 = GenerationResult(tokens=np.zeros((2, 2), np.int32),
-                          request_ids=np.array([3, 0]))
-    engine._record_generation(r2, prompt_len=2, n_new=2, request_source=None)
+    engine.record(np.zeros((2, 2), np.int32), prompt_len=2,
+                  request_ids=np.array([3, 0]))
     again = engine.response_lineage(r1, rows=[1], upstream="raw")
     np.testing.assert_array_equal(again, got1)
     assert sess.counters["cross_composes"] == 1      # NOT recomposed
@@ -513,27 +510,19 @@ def test_upstream_engine_requires_explicit_request_ids():
     the arange() default must never silently fabricate it, and a bad batch
     must fail BEFORE mutating the serving index."""
     prep, exported, _, _ = _capability_fixture()
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:reqids",
-                            upstream=prep.export(exported.dataset_id))
-    r = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                         request_ids=np.arange(3))
+    engine = ServeEngine("serve:reqids",
+                         upstream=prep.export(exported.dataset_id))
     with pytest.raises(ValueError, match="explicit request_ids"):
-        engine._record_generation(r, prompt_len=2, n_new=2,
-                                  request_source=None,
-                                  request_ids_given=False)
+        engine.record(np.zeros((3, 2), np.int32), prompt_len=2)
     # out-of-range rows fail before add_source: no orphan requests@N
-    bad = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                          request_ids=np.array([0, 1, 99]))
     with pytest.raises(ValueError, match="boundary dataset"):
-        engine._record_generation(bad, prompt_len=2, n_new=2,
-                                  request_source=None)
+        engine.record(np.zeros((3, 2), np.int32), prompt_len=2,
+                      request_ids=np.array([0, 1, 99]))
     assert not any(d.startswith("requests@") for d in engine.prov.datasets)
     assert not engine.catalog.links
     # -1 = request with no upstream origin: records fine, traces to nothing
-    ok = GenerationResult(tokens=np.zeros((2, 2), np.int32),
-                          request_ids=np.array([2, -1]))
-    engine._record_generation(ok, prompt_len=2, n_new=2, request_source=None)
+    ok = engine.record(np.zeros((2, 2), np.int32), prompt_len=2,
+                       request_ids=np.array([2, -1]))
     got = engine.response_lineage_batch(ok, [[0], [1]], upstream="raw")
     assert [g.tolist() for g in got] == [[3], []]
 
@@ -542,11 +531,10 @@ def test_upstream_tuple_attach_validates_dataset():
     prep, exported, _, _ = _capability_fixture()
     catalog = ProvCatalog()
     catalog.register("prep", prep)
-    engine = object.__new__(ServeEngine)
     with pytest.raises(KeyError):
-        engine._init_provenance(
-            "serve:typo", upstream=(catalog, "prep/definitely-missing"))
-    engine._init_provenance(
+        ServeEngine("serve:typo",
+                    upstream=(catalog, "prep/definitely-missing"))
+    engine = ServeEngine(
         "serve:ok", upstream=(catalog, qualify("prep", exported.dataset_id)))
     assert engine.catalog is catalog and "serve" in catalog.members
 
@@ -651,8 +639,7 @@ def test_catalog_resolution_respects_capabilities():
 def test_serve_engine_upstream_holds_no_prep_index():
     prep, exported, sibling, _ = _capability_fixture()
     handle = prep.export(exported.dataset_id)
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:captest", upstream=handle)
+    engine = ServeEngine("serve:captest", upstream=handle)
     assert all(v is not prep for v in vars(engine).values())
     assert engine.catalog.member_of(prep) is None
     # the registered upstream member is the read-only capability
@@ -661,9 +648,8 @@ def test_serve_engine_upstream_holds_no_prep_index():
     with pytest.raises(CapabilityError):
         up.record([], "x", None, None)
     # lineage still reaches prep/raw through the federation
-    r = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                         request_ids=np.array([0, 2, 2]))
-    engine._record_generation(r, prompt_len=2, n_new=2, request_source=None)
+    r = engine.record(np.zeros((3, 2), np.int32), prompt_len=2,
+                      request_ids=np.array([0, 2, 2]))
     got = engine.response_lineage(r, rows=[1], upstream="raw")
     # request row 1 aligned to exported row 2, which is raw row 3
     np.testing.assert_array_equal(got, [3])
@@ -678,28 +664,24 @@ def test_serve_engine_prov_index_shim_warns_once_and_matches():
     clean = s.filter_rows(np.array([1, 0, 1, 1, 0, 1], bool))
     clean.mark_sink()
     serve_engine._DEPRECATION_WARNED.discard("prov_index")
-    e = object.__new__(ServeEngine)
     with pytest.warns(DeprecationWarning, match="prov_index"):
-        e._init_provenance("serve:shim", prov_index=prep)
+        e = ServeEngine("serve:shim", prov_index=prep)
     # single-entry catalog wrap: the engine records INTO the passed index
     assert e.prov is prep
     assert list(e.catalog.members) == ["serve"]
-    e2 = object.__new__(ServeEngine)
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
-        e2._init_provenance("serve:shim2", prov_index=prep)   # silent now
+        ServeEngine("serve:shim2", prov_index=prep)   # silent now
     # identical lineage to the legacy merged-index behavior
-    r = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                         request_ids=np.array([0, 2, 2]))
-    e._record_generation(r, prompt_len=2, n_new=2,
-                         request_source=clean.dataset_id)
+    r = e.record(np.zeros((3, 2), np.int32), prompt_len=2,
+                 request_ids=np.array([0, 2, 2]),
+                 request_source=clean.dataset_id)
     got = e.response_lineage(r, rows=[1], upstream="raw")
     np.testing.assert_array_equal(got, tqp.ref_q2(prep, r.response_dataset,
                                                   [1], "raw"))
     with pytest.raises(ValueError, match="not both"):
-        e3 = object.__new__(ServeEngine)
-        e3._init_provenance("serve:both", upstream=prep.export("raw"),
-                            prov_index=prep)
+        ServeEngine("serve:both", upstream=prep.export("raw"),
+                    prov_index=prep)
 
 
 # ===========================================================================

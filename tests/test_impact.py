@@ -335,9 +335,7 @@ def test_serve_engine_erasure_impact():
     clean = s.value_transform("x", "scale", factor=2.0)
     clean.mark_sink()
 
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:test",
-                            upstream=prep.export(clean.dataset_id))
+    engine = ServeEngine("serve:test", upstream=prep.export(clean.dataset_id))
     # simulate one recorded request batch linked to upstream rows
     req = Table.from_columns({
         "x": rng.normal(size=4).astype(np.float32)})
@@ -354,6 +352,4 @@ def test_serve_engine_erasure_impact():
     np.testing.assert_array_equal(
         by_ref[qualify(engine._serve_name, "requests@0")], [0, 3])
     with pytest.raises(ValueError, match="source="):
-        e2 = object.__new__(ServeEngine)
-        e2._init_provenance("serve:bare")
-        e2.erasure_impact([0])
+        ServeEngine("serve:bare").erasure_impact([0])

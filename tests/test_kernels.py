@@ -2,6 +2,10 @@
 
 All kernels are integer/boolean — assertions are EXACT equality.
 """
+import contextlib
+import functools
+from collections import defaultdict
+
 import numpy as np
 import pytest
 import jax
@@ -228,34 +232,94 @@ def test_batched_walk_property(hops, seed):
 # ---------------------------------------------------------------------------
 # fused walk over real pipelines: query-layer + session routing parity
 # ---------------------------------------------------------------------------
-def test_fused_walk_record_masks_parity_pipegen():
+SEEDS = range(12)
+CAPTURES = ("structured", "coo")
+DIRECTIONS = ("fwd", "bwd")
+
+
+@functools.lru_cache(maxsize=None)
+def _pipegen_index(seed, capture):
+    """A random pipeline (``pipegen``), captured structured or forced COO.
+
+    Scaled to 480-1,568 source rows, so that every structured chain's
+    planes outweigh a sparse plan's gather answer table (64 KiB) and the
+    gather route takes it.  Every op's other input is a fresh source, so
+    the src -> sink flow is one linear chain.  The walks below only read
+    the index, so one build serves every case of a (seed, capture)."""
+    import pipegen
+    from repro.core.capture import force_coo_capture
+
+    scope = force_coo_capture() if capture == "coo" else contextlib.nullcontext()
+    with scope:
+        idx, sink, _ = pipegen.random_pipeline(seed, scale=32)
+    return idx, sink
+
+
+def _pipegen_probe(seed, capture, direction):
+    """``(idx, src, dst, rows)``: a sparse 4-probe stack at the probed end
+    (probe 0 empty)."""
+    idx, sink = _pipegen_index(seed, capture)
+    src, dst = ("src", sink) if direction == "fwd" else (sink, "src")
+    rng = np.random.default_rng([seed, DIRECTIONS.index(direction)])
+    rows = rng.random((4, idx.datasets[src].n_rows)) < 0.01
+    rows[0] = False
+    return idx, src, dst, rows
+
+
+def _dense_launches():
+    return K.launch_counts().get("batched_walk", 0)
+
+
+def _assert_route(capture, gather_walks, dense_launches):
+    """A structured chain takes the gather route; a forced-COO chain (under
+    the plane cap) the one ``batched_walk`` launch."""
+    if capture == "structured":
+        assert (gather_walks, dense_launches) == (1, 0)
+    else:
+        assert (gather_walks, dense_launches) == (0, 1)
+
+
+@pytest.mark.parametrize("direction", DIRECTIONS)
+@pytest.mark.parametrize("capture", CAPTURES)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_fused_walk_record_masks_parity_pipegen(seed, capture, direction):
     """Fused walker vs the full per-op walkers on randomized pipelines
-    (outer joins / appends with -1 sentinels included), both directions.
-    None (non-linear subgraph) is a legal answer; a mask is not allowed to
-    disagree."""
+    (outer joins / appends with -1 sentinels included): the same mask
+    stack, through the route the chain's capture decides."""
+    from repro.core import query as Q
+
+    idx, src, dst, rows = _pipegen_probe(seed, capture, direction)
+    walker = (Q.forward_record_masks_batch if direction == "fwd"
+              else Q.backward_record_masks_batch)
+    want = walker(idx, src, rows).get(
+        dst, np.zeros((len(rows), idx.datasets[dst].n_rows), bool))
+    counters = defaultdict(int)
+    before = _dense_launches()
+    got = Q.fused_walk_record_masks_batch(idx, src, dst, rows, direction,
+                                          counters=counters)
+    assert got is not None          # a linear chain passes the audit
+    np.testing.assert_array_equal(got, want)
+    _assert_route(capture, counters["gather_walk"],
+                  _dense_launches() - before)
+
+
+def test_fused_walk_accepts_real_chains():
+    """The linearity audit must accept real chains: the unscaled random
+    pipelines fuse (None, the fallback, is legal per chain, not for all),
+    and a fused answer agrees with the per-op walker."""
     import pipegen
     from repro.core import query as Q
 
     fused_hits = 0
-    for seed in range(12):
+    for seed in SEEDS:
         idx, sink, rng = pipegen.random_pipeline(seed)
-        n_src = idx.datasets["src"].n_rows
-        n_dst = idx.datasets[sink].n_rows
-        B = 4
-        rows_b = rng.random((B, n_src)) < 0.3
-        ref_m = Q.forward_record_masks_batch(idx, "src", rows_b).get(
-            sink, np.zeros((B, n_dst), bool))
+        rows_b = rng.random((4, idx.datasets["src"].n_rows)) < 0.3
         got = Q.fused_walk_record_masks_batch(idx, "src", sink, rows_b, "fwd")
         if got is not None:
             fused_hits += 1
-            np.testing.assert_array_equal(got, ref_m)
-        rows_d = rng.random((B, n_dst)) < 0.3
-        refb = Q.backward_record_masks_batch(idx, sink, rows_d).get(
-            "src", np.zeros((B, n_src), bool))
-        gotb = Q.fused_walk_record_masks_batch(idx, sink, "src", rows_d, "bwd")
-        if gotb is not None:
-            np.testing.assert_array_equal(gotb, refb)
-    assert fused_hits > 0  # the linearity audit must accept real chains
+            want = Q.forward_record_masks_batch(idx, "src", rows_b)[sink]
+            np.testing.assert_array_equal(got, want)
+    assert fused_hits > 0
 
 
 def test_fused_walk_rejects_diamond():
@@ -282,26 +346,58 @@ def test_fused_walk_identity_pair():
     np.testing.assert_array_equal(got, rows)
 
 
-def test_session_fused_walk_routing_parity():
-    """QuerySession(fused_walk=True) answers byte-identically to the plain
-    walk and bumps the fused_walk counter when the chain fuses."""
-    import pipegen
+def _session_parity(idx, src, dst, rows, direction):
+    """Run one record plan through a fused-walk session and a host-walk
+    session: both answer alike; returns the fused session's counters and
+    the ``batched_walk`` launches it made."""
     from repro.provenance import prov
     from repro.provenance.session import QuerySession
 
-    for seed in (0, 5, 9):
-        idx, sink, rng = pipegen.random_pipeline(seed)
-        n = idx.datasets["src"].n_rows
-        rows_b = rng.random((4, n)) < 0.3
-        s_on = QuerySession(idx, fused_walk=True, use_hopcache=False)
-        s_off = QuerySession(idx, fused_walk=False, use_hopcache=False)
-        plan = prov(idx).source("src").rows_batch(rows_b).forward().to(sink).plan()
-        got = s_on.run(plan)
-        want = s_off.run(plan)
-        assert len(got) == len(want)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(g, w)
-        assert s_off.counters["fused_walk"] == 0
+    s_on = QuerySession(idx, fused_walk=True, use_hopcache=False)
+    s_off = QuerySession(idx, fused_walk=False, use_hopcache=False)
+    q = prov(idx).source(src).rows_batch(rows)
+    plan = (q.forward() if direction == "fwd" else q.backward()).to(dst).plan()
+    before = _dense_launches()
+    got = s_on.run(plan)
+    launches = _dense_launches() - before
+    want = s_off.run(plan)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert s_off.counters["fused_walk"] == 0
+    return s_on.counters, launches
+
+
+@pytest.mark.parametrize("direction", DIRECTIONS)
+@pytest.mark.parametrize("capture", CAPTURES)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_session_fused_walk_routing_parity(seed, capture, direction):
+    """QuerySession(fused_walk=True) answers byte-identically to the plain
+    walk and counts the fused walk on the route the chain's capture
+    decides."""
+    idx, src, dst, rows = _pipegen_probe(seed, capture, direction)
+    counters, launches = _session_parity(idx, src, dst, rows, direction)
+    assert counters["fused_walk"] == 1
+    _assert_route(capture, counters["gather_walk"], launches)
+
+
+@pytest.mark.parametrize("direction", DIRECTIONS)
+@pytest.mark.parametrize("capture", CAPTURES)
+def test_session_fused_walk_diamond_takes_the_host_walk(capture, direction):
+    """A diamond fails the linearity audit: the fused session walks it on
+    the host, with no device walk, and answers like the plain walk."""
+    import pipegen
+    from repro.core.capture import force_coo_capture
+
+    scope = force_coo_capture() if capture == "coo" else contextlib.nullcontext()
+    with scope:
+        idx, sink = pipegen.diamond_pipeline(0)
+    src, dst = ("src", sink) if direction == "fwd" else (sink, "src")
+    rows = np.random.default_rng(3).random((3, idx.datasets[src].n_rows)) < 0.3
+    counters, launches = _session_parity(idx, src, dst, rows, direction)
+    assert counters["fused_walk"] == 0 and counters["gather_walk"] == 0
+    assert launches == 0
+    assert counters["host_walk_ns"] > 0
 
 
 # ---------------------------------------------------------------------------

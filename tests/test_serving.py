@@ -17,8 +17,8 @@ Four pillars:
 * **Engine recording** — ``ServeEngine`` provenance invariants the tier
   rests on: gid collision looping, lineage-vs-hand-built-plan parity,
   bare-ref qualification through ``as_backend()``, the ``prov_index=``
-  deprecation warning attributing to the CALLER's file, and seeded
-  non-greedy sampling.
+  deprecation warning attributing to the CALLER's file, and ``record``
+  over a seeded token batch.
 
 pytest-timeout guards these in CI; locally (where the plugin may be
 absent) an autouse SIGALRM fixture aborts a wedged async test instead of
@@ -395,29 +395,24 @@ def test_backend_failure_fans_out_and_releases():
 # ServeEngine recording invariants + tier integration
 # ===========================================================================
 def _recorded_engine(b=4):
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:tiertest")
-    r = GenerationResult(tokens=np.zeros((b, 2), np.int32),
-                         request_ids=np.arange(b))
-    engine._record_generation(r, prompt_len=2, n_new=2, request_source=None)
+    engine = ServeEngine("serve:tiertest")
+    r = engine.record(np.zeros((b, 2), np.int32), prompt_len=2,
+                      request_ids=np.arange(b))
     return engine, r
 
 
 def test_record_gid_collision_loops_to_free_slot():
-    engine = object.__new__(ServeEngine)
-    engine._init_provenance("serve:gid")
+    engine = ServeEngine("serve:gid")
     # an earlier generation (or a sibling engine on a shared index) already
     # owns slot 0 — recording must skip it, not collide
     engine.prov.add_source("responses@0", Table.from_columns(
         {"z": np.zeros(2, np.float32)}))
-    r = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                         request_ids=np.arange(3))
-    engine._record_generation(r, prompt_len=1, n_new=2, request_source=None)
+    r = engine.record(np.zeros((3, 2), np.int32), prompt_len=1,
+                      request_ids=np.arange(3))
     assert (r.request_dataset, r.response_dataset) == \
         ("requests@1", "responses@1")
-    r2 = GenerationResult(tokens=np.zeros((2, 2), np.int32),
-                          request_ids=np.arange(2))
-    engine._record_generation(r2, prompt_len=1, n_new=2, request_source=None)
+    r2 = engine.record(np.zeros((2, 2), np.int32), prompt_len=1,
+                       request_ids=np.arange(2))
     assert r2.response_dataset == "responses@2"
     # both recordings answer lineage independently
     np.testing.assert_array_equal(engine.response_lineage(r, rows=[2]), [2])
@@ -466,8 +461,7 @@ def test_prov_index_deprecation_attributes_callers_file():
     serve_engine._DEPRECATION_WARNED.discard("prov_index")
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        e = object.__new__(ServeEngine)
-        e._init_provenance("serve:warnfile", prov_index=prep)
+        ServeEngine("serve:warnfile", prov_index=prep)
     dep = [w for w in caught if issubclass(w.category, DeprecationWarning)
            and "prov_index" in str(w.message)]
     assert len(dep) == 1
@@ -476,23 +470,27 @@ def test_prov_index_deprecation_attributes_callers_file():
     assert dep[0].filename == __file__
 
 
-def test_generate_sampling_seeded_and_in_vocab():
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-    from repro.configs.registry import get_smoke_config
-    from repro.models.registry import get_model
-
-    cfg = get_smoke_config("olmo-1b")
-    params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
-    engine = ServeEngine(cfg, params, max_seq=4 + 3, dtype=jnp.float32)
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(1, cfg.vocab, (2, 4)).astype(np.int32)
-    a = engine.generate(prompts, n_new=3, greedy=False, sample_seed=7,
-                        record_provenance=True)
-    b = engine.generate(prompts, n_new=3, greedy=False, sample_seed=7)
-    # seeded sampling is deterministic — the reproducibility contract the
-    # provenance record rests on
-    np.testing.assert_array_equal(a.tokens, b.tokens)
-    assert a.tokens.dtype == np.int32 and a.tokens.shape == (2, 3)
-    assert int(a.tokens.min()) >= 0 and int(a.tokens.max()) < cfg.vocab
-    np.testing.assert_array_equal(engine.response_lineage(a, rows=[1]), [1])
+def test_record_seeded_tokens_registers_the_pair_and_lineage():
+    """``record`` over a seeded (B, n_new) token batch registers exactly
+    the requests@N / responses@N pair, one HAUGMENT op between them, and
+    maps each response row to its request row."""
+    rng = np.random.default_rng(7)
+    tokens = rng.integers(0, 50_000, (5, 3)).astype(np.int32)
+    engine = ServeEngine("serve:record")
+    r = engine.record(tokens, prompt_len=4, request_ids=np.arange(5) + 10)
+    assert isinstance(r, GenerationResult)
+    np.testing.assert_array_equal(r.tokens, tokens)
+    assert (r.request_dataset, r.response_dataset) == \
+        ("requests@0", "responses@0")
+    assert engine.prov.datasets[r.request_dataset].n_rows == 5
+    assert engine.prov.datasets[r.response_dataset].n_rows == 5
+    (op,) = engine.prov.ops
+    assert (op.input_ids, op.output_id) == ([r.request_dataset],
+                                            r.response_dataset)
+    assert op.info.params == {"n_new": 3, "prompt_len": 4}
+    got = engine.response_lineage_batch(r, [[i] for i in range(5)])
+    assert [g.tolist() for g in got] == [[i] for i in range(5)]
+    np.testing.assert_array_equal(engine.response_lineage(r, rows=[1, 3]),
+                                  [1, 3])
+    with pytest.raises(ValueError, match="n_new"):
+        engine.record(tokens[0], prompt_len=4)

@@ -399,19 +399,17 @@ def test_shims_warn_on_ambiguous_probes():
 def test_serve_engines_sharing_one_index_never_collide():
     """Two engines over ONE prov index (the documented pattern) must not
     overwrite each other's requests@N/responses@N datasets."""
-    from repro.serve.engine import GenerationResult, ServeEngine
+    from repro.serve.engine import ServeEngine
 
     idx = ProvenanceIndex("shared-serve")
-    e1 = object.__new__(ServeEngine)   # skip model init: only the capture
-    e2 = object.__new__(ServeEngine)   # path is under test
-    for e in (e1, e2):
-        e.prov, e._n_generations = idx, 0
-    r1 = GenerationResult(tokens=np.zeros((3, 2), np.int32),
-                          request_ids=np.arange(3))
-    e1._record_generation(r1, prompt_len=2, n_new=2, request_source=None)
-    r2 = GenerationResult(tokens=np.zeros((4, 2), np.int32),
-                          request_ids=np.arange(4))
-    e2._record_generation(r2, prompt_len=2, n_new=2, request_source=None)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        e1 = ServeEngine(prov_index=idx)
+        e2 = ServeEngine(prov_index=idx)
+    r1 = e1.record(np.zeros((3, 2), np.int32), prompt_len=2,
+                   request_ids=np.arange(3))
+    r2 = e2.record(np.zeros((4, 2), np.int32), prompt_len=2,
+                   request_ids=np.arange(4))
     assert r1.response_dataset != r2.response_dataset
     assert r1.request_dataset != r2.request_dataset
     np.testing.assert_array_equal(

@@ -42,6 +42,7 @@ from repro.core.provtensor import (
 from repro.core.recompute import recompute_rows
 from repro.core.spill import SpillPolicy, SpillStore, resolve_spill
 from repro.dataprep.table import Table
+from repro.provenance import QuerySession, prov
 
 
 # ===========================================================================
@@ -330,6 +331,74 @@ class TestHopcacheSpill:
         assert st["evictions"] > 0 and st["spills"] > 0
         # spilled relations are still "contained" and fault back
         assert st["spilled_entries"] > 0 or st["rehydrations"] > 0
+
+    def test_append_stream_both_tiers_bounded_and_exact(self):
+        """The streaming scenario with both tiers spilling at once: a mixed
+        append stream (identity / filter / shuffle / appended block) under
+        batched probes keeps op tensors and composed relations within their
+        budgets, and answers exactly like the same stream without spill."""
+        tensor_budget, cache_budget = 16 << 10, 32 << 10
+        idx = ProvenanceIndex("stream",
+                              spill=SpillPolicy(budget_bytes=tensor_budget))
+        ci = idx.composed(memory_budget_bytes=cache_budget,
+                          spill=SpillPolicy(disk_budget_bytes=256 << 20))
+        sess = QuerySession(idx, composed=ci)
+        ref = ProvenanceIndex("streamref")
+        ref_sess = QuerySession(ref, use_hopcache=False)
+        for index in (idx, ref):
+            index.add_source("d0", _table(256))
+        rng = np.random.default_rng(0)
+        cur, n = "d0", 256
+        for i in range(120):
+            out, inputs, kind = f"d{i + 1}", [cur], i % 4
+            if kind == 0:
+                n_out = n
+                info = lambda: _identity_info(f"t{i}", n)  # noqa: E731
+            elif kind == 1:
+                kept = np.flatnonzero(rng.random(n) > 0.03)
+                n_out = len(kept)
+                info = lambda: _filter_info(f"f{i}", kept, n)  # noqa: E731
+            elif kind == 2:
+                perm = rng.permutation(n)
+                n_out = n
+                info = lambda: _gather_info(f"g{i}", perm, n)  # noqa: E731
+            else:
+                blk = f"blk{i}"
+                for index in (idx, ref):
+                    index.add_source(blk, _table(8, i))
+                inputs, n_out = [cur, blk], n + 8
+                info = lambda: CaptureInfo(  # noqa: E731
+                    op_name="append_rows", category=OpCategory.APPEND,
+                    contextual=False, n_out=n + 8, n_in=[n, 8],
+                    attr_maps=[AttrMap("identity"), AttrMap("identity")])
+            for index in (idx, ref):
+                index.record(inputs, out, _table(n_out, i), info())
+            cur, n = out, n_out
+            if i % 20 == 19:
+                fwd = rng.random((4, 256)) < 0.02
+                bwd = rng.random((4, n)) < 0.02
+                for got, want in (
+                    (sess.run(prov(idx).source("d0").rows_batch(fwd)
+                              .forward().to(cur).plan()),
+                     ref_sess.run(prov(ref).source("d0").rows_batch(fwd)
+                                  .forward().to(cur).plan())),
+                    (sess.run(prov(idx).source(cur).rows_batch(bwd)
+                              .backward().to("d0").plan()),
+                     ref_sess.run(prov(ref).source(cur).rows_batch(bwd)
+                                  .backward().to("d0").plan())),
+                ):
+                    for g, w in zip(got, want):
+                        np.testing.assert_array_equal(g, w)
+                sp, cs = idx.stats()["spill"], ci.stats()
+                assert sp["resident_bytes"] <= tensor_budget, sp
+                assert cs["bytes"] <= cache_budget * ci._spill.high_watermark, cs
+        assert idx.stats()["spill"]["spilled_ops"] > 0
+        assert ci.stats()["spills"] > 0
+        want = ComposedIndex(ref).relation("d0", cur)
+        got = ci.relation("d0", cur)
+        assert np.array_equal(
+            np.asarray(want.todense() if hasattr(want, "todense") else want),
+            np.asarray(got.todense() if hasattr(got, "todense") else got))
 
     @pytest.mark.parametrize("backend", ["csr", "bitplane", "auto"])
     def test_spilled_entry_roundtrip(self, backend):
